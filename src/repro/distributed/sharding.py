@@ -20,7 +20,7 @@ schedule visible to the roofline analysis.
 from __future__ import annotations
 
 import re
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import numpy as np
@@ -150,6 +150,17 @@ def param_shardings(params: PyTree, mesh: Mesh, fsdp: bool = False,
                            layout)
         return NamedSharding(mesh, spec)
     return jax.tree_util.tree_map_with_path(one, params)
+
+
+def init_sharded(init: Callable[[], PyTree], mesh: Mesh, *,
+                 fsdp: bool = False, layout: str = "tp") -> PyTree:
+    """Run the parameter initializer ``init`` as one compiled program whose
+    outputs land straight in their rule shardings: no eager per-layer
+    arrays beside the stacked ones, no host copy, no replicated staging
+    tree — peak device memory is the sharded weights themselves."""
+    shapes = jax.eval_shape(init)
+    return jax.jit(init, out_shardings=param_shardings(
+        shapes, mesh, fsdp, layout))()
 
 
 def param_specs(params: PyTree, mesh: Mesh, fsdp: bool = False,

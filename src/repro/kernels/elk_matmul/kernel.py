@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names the Mosaic params class TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref):
     @pl.when(pl.program_id(2) == 0)
@@ -71,7 +67,7 @@ def elk_matmul(x: jax.Array, y: jax.Array, *, bm: int = 256, bn: int = 256,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, y)

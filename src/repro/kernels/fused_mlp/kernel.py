@@ -30,9 +30,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_mlp.ref import _ACT
 
-# jax < 0.5 names the Mosaic params class TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+# VMEM a grid step's blocks may fill.  v5e and v6e have 128 MiB per core;
+# the compiler's default scoped limit (16 MiB on v5e) is far below what one
+# double-buffered (d, bf) weight slab needs at 5k-8k model widths, so the
+# kernel sizes its own limit from the blocks it picked.
+_VMEM_BUDGET = 64 << 20
+
+
+def _vmem_bytes(bm: int, bf: int, d: int, dout: int, n_in: int,
+                itemsize: int) -> int:
+    """Per-step VMEM: double-buffered x, ``n_in`` (d, bf) input slabs, the
+    (bf, dout) down slab and the out block, plus the fp32 accumulator and
+    the fp32 (bm, bf) intermediates."""
+    blocks = bm * d + n_in * d * bf + bf * dout + bm * dout
+    return 2 * itemsize * blocks + 4 * bm * dout + 4 * n_in * bm * bf
+
+
+def _ff_block(bf: int, bm: int, d: int, ff: int, dout: int, n_in: int,
+              itemsize: int) -> int:
+    """Largest multiple of 128 that is <= ``bf``, divides ``ff`` (so no
+    weight is padded per call) and keeps the step within the VMEM budget.
+    Widths with no such divisor keep ``min(bf, ff)`` and pad."""
+    for cand in range(bf - bf % 128, 127, -128):
+        if ff % cand == 0 and _vmem_bytes(bm, cand, d, dout, n_in,
+                                          itemsize) <= _VMEM_BUDGET:
+            return cand
+    return min(bf, ff)
 
 
 def _make_kernel(act_fn, gated: bool, bias: bool):
@@ -96,7 +119,9 @@ def fused_mlp_kernel(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
         return jnp.zeros((*lead, dout), x.dtype)
     x2 = x.reshape(m, d)
     gated, bias = w_gate is not None, b_up is not None
-    bm, bf = min(bm, m), min(bf, ff)
+    n_in = 2 if gated else 1
+    bm = min(bm, m)
+    bf = _ff_block(bf, bm, d, ff, dout, n_in, x.dtype.itemsize)
     mp = -(-m // bm) * bm
     ffp = -(-ff // bf) * bf
     if mp != m:
@@ -129,8 +154,10 @@ def fused_mlp_kernel(x: jax.Array, w_up: jax.Array, w_down: jax.Array,
         out_specs=pl.BlockSpec((bm, dout), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((bm, dout), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((mp, dout), x.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(16 << 20, _vmem_bytes(
+                bm, bf, d, dout, n_in, x.dtype.itemsize) * 5 // 4)),
         interpret=interpret,
     )(*args)
     return out[:m].reshape(*lead, dout)

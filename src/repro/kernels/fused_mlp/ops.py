@@ -2,9 +2,11 @@
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.dispatch import dispatch
 from repro.kernels.fused_mlp.kernel import fused_mlp_kernel
@@ -16,15 +18,57 @@ def fused_mlp(x: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
               b_up: Optional[jax.Array] = None,
               b_down: Optional[jax.Array] = None,
               act: str = "silu", bm: int = 128, bf: int = 512,
-              force_kernel: bool = False) -> jax.Array:
+              force_kernel: bool = False,
+              mesh: Optional[Mesh] = None) -> jax.Array:
     """up-proj -> activation -> down-proj without storing the intermediate.
 
     GLU when ``w_gate`` is given, plain MLP (optional fc biases) otherwise.
+    On a mesh of more than one device the kernel runs per shard (the
+    compiler cannot partition a Mosaic call): see ``_per_shard``.
     """
+    def kernel(interpret):
+        def call(x, w_up, w_down, w_gate, b_up, b_down):
+            return fused_mlp_kernel(x, w_up, w_down, w_gate, b_up, b_down,
+                                    act=act, bm=bm, bf=bf,
+                                    interpret=interpret)
+        if mesh is None or mesh.size == 1:
+            return call(x, w_up, w_down, w_gate, b_up, b_down)
+        return _per_shard(call, mesh, x, w_up, w_down, w_gate, b_up, b_down)
+
     return dispatch(
-        lambda interpret: fused_mlp_kernel(x, w_up, w_down, w_gate, b_up,
-                                           b_down, act=act, bm=bm, bf=bf,
-                                           interpret=interpret),
+        kernel,
         lambda: fused_mlp_ref(x, w_up, w_down, w_gate=w_gate, b_up=b_up,
                               b_down=b_down, act=act),
         force_kernel=force_kernel)
+
+
+def _per_shard(call, mesh: Mesh, x, w_up, w_down, w_gate, b_up, b_down):
+    """``shard_map`` the kernel: rows split over the batch axes (pod, data)
+    where the leading dim divides, the FF dim over ``model`` Megatron-style
+    (column-parallel up/gate, row-parallel down, one psum) where it
+    divides; everything else is gathered to each shard."""
+    bp = tuple(ax for ax in ("pod", "data") if ax in mesh.shape)
+    dp = math.prod(mesh.shape[ax] for ax in bp)
+    rows = bp if bp and x.shape[0] % dp == 0 else None
+    tp = mesh.shape.get("model", 1)
+    ff = "model" if tp > 1 and w_up.shape[1] % tp == 0 else None
+    x_spec = P(rows, *([None] * (x.ndim - 1)))
+    bias_after = b_down if ff else None     # added once, after the psum
+
+    names = ("x", "w_up", "w_down", "w_gate", "b_up", "b_down")
+    specs = dict(x=x_spec, w_up=P(None, ff), w_down=P(ff, None),
+                 w_gate=P(None, ff), b_up=P(ff), b_down=P())
+    vals = dict(zip(names, (x, w_up, w_down, w_gate, b_up,
+                            None if ff else b_down)))
+    given = {k: v for k, v in vals.items() if v is not None}
+
+    def body(given):
+        out = call(*(given.get(k) for k in names))
+        return jax.lax.psum(out, ff) if ff else out
+
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=({k: specs[k] for k in given},),
+                        out_specs=x_spec, check_vma=False)(given)
+    if bias_after is not None:
+        out = out + bias_after.astype(out.dtype)
+    return out

@@ -9,19 +9,14 @@ initialization and only then calls these.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh, with explicit Auto axis types where the installed jax
-    supports them (jax < 0.5 has neither AxisType nor the kwarg — its meshes
-    are implicitly Auto)."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+    """jax.make_mesh with Auto axes: the sharding rules place arrays with
+    ``NamedSharding`` and leave propagation to the compiler (``make_mesh``
+    defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -33,10 +28,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Mesh over the locally visible devices (tests / CPU examples)."""
+    """(data, model) mesh over the first ``data * model`` local devices.
+    Raises when fewer devices are visible: a smaller mesh would hide the
+    device count from every number measured on it."""
     n = len(jax.devices())
     if data * model > n:
-        data, model = n, 1
+        raise ValueError(f"a ({data}, {model}) mesh needs {data * model} "
+                         f"devices; {n} visible")
     return _make_mesh((data, model), ("data", "model"))
 
 

@@ -21,6 +21,8 @@ import time
 import jax
 
 from repro.configs import ARCH_IDS, canonical, get_config, get_smoke_config
+from repro.distributed.sharding import init_sharded
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import transformer as tfm
 from repro.serve.engine import ServeConfig, ServeEngine, elk_serve_config
@@ -174,6 +176,7 @@ def main() -> None:
                     help="write --trace stats to this JSON file")
     args = ap.parse_args()
 
+    use_compile_cache()
     arch = canonical(args.arch)
     cfg = get_smoke_config(arch) if args.smoke else get_config(arch)
     mesh = (make_production_mesh() if args.production_mesh
@@ -209,8 +212,9 @@ def main() -> None:
             prefetch_depth=max(args.prefetch_depth, 1),
             kv_dtype=args.kv_dtype)
 
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    eng = ServeEngine(cfg, mesh, params, scfg)
+    eng = ServeEngine(cfg, mesh, init_sharded(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg), mesh,
+        fsdp=scfg.mode == "elk_stream"), scfg)
 
     if args.trace > 0:
         stats = _run_trace(eng, args, cfg.vocab_size)

@@ -18,6 +18,7 @@ import jax
 
 from repro.configs import ARCH_IDS, canonical, get_config, get_smoke_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -43,6 +44,7 @@ def main() -> None:
                     help="use the (16,16) mesh (needs 256 devices)")
     args = ap.parse_args()
 
+    use_compile_cache()
     arch = canonical(args.arch)
     cfg = get_smoke_config(arch) if args.smoke else get_config(arch)
     mesh = (make_production_mesh() if args.production_mesh

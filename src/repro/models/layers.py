@@ -213,20 +213,21 @@ def linear(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None
     return out
 
 
-def mlp(x: jax.Array, p: dict, cfg: ModelConfig) -> jax.Array:
+def mlp(x: jax.Array, p: dict, cfg: ModelConfig, mesh=None) -> jax.Array:
     """MLP block on the fused runtime path (kernels/fused_mlp).
 
     On TPU (or under ``force_kernels``) the whole up-proj -> activation ->
     down-proj chain runs as one Pallas grid with the intermediate staged
     in VMEM — the runtime twin of the FusedOp the fusion pass hands the
-    scheduler.  The CPU fallback is the exact einsum composition this
-    function used before fusion existed, so outputs are unchanged."""
+    scheduler; on a multi-device ``mesh`` it runs once per shard.  The CPU
+    fallback is the exact einsum composition this function used before
+    fusion existed, so outputs are unchanged."""
     from repro.kernels.fused_mlp.ops import fused_mlp
     if cfg.gated_mlp:
         return fused_mlp(x, p["w_up"], p["w_down"], w_gate=p["w_gate"],
-                         act=cfg.mlp_act)
+                         act=cfg.mlp_act, mesh=mesh)
     return fused_mlp(x, p["w_up"], p["w_down"], b_up=p.get("b_up"),
-                     b_down=p.get("b_down"), act=cfg.mlp_act)
+                     b_down=p.get("b_down"), act=cfg.mlp_act, mesh=mesh)
 
 
 def mlp_params(rng, cfg: ModelConfig, d: int, ff: int, dtype) -> dict:

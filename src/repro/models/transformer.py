@@ -175,14 +175,16 @@ def init_params(rng, cfg: ModelConfig,
     params["prefix"] = [decoder_layer_params(pre_keys[i], cfg, kinds[i], dtype)
                         for i in range(prefix)]
 
+    # vmap over the block keys builds each stacked leaf directly (the same
+    # values as stacking per-block draws), so no per-layer copy is ever
+    # live beside the stack
     blocks = []
     blk_keys = jax.random.split(kblk, max(n_blocks * period, 1))
     for slot in range(period):
         kind = kinds[prefix + slot]
-        per_block = [decoder_layer_params(blk_keys[b * period + slot], cfg,
-                                          kind, dtype)
-                     for b in range(n_blocks)]
-        blocks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_block))
+        blocks.append(jax.vmap(
+            lambda k, kind=kind: decoder_layer_params(k, cfg, kind, dtype))(
+                blk_keys[slot::period][:n_blocks]))
     params["blocks"] = blocks
     return params
 
@@ -416,9 +418,9 @@ def _ffn(x, p, cfg: ModelConfig, kind: str, mode: str = "train",
                               mesh=mesh)
         out = out.reshape(b, s, d)
         if cfg.moe_shared_d_ff:
-            out = out + mlp(x, p["shared_mlp"], cfg)
+            out = out + mlp(x, p["shared_mlp"], cfg, mesh)
         return out
-    return mlp(x, p["mlp"], cfg)
+    return mlp(x, p["mlp"], cfg, mesh)
 
 
 def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,

@@ -283,7 +283,7 @@ class ServeEngine:
                                              prefetch=scfg.prefetch_depth)
         else:
             def decode(params, token, cache):
-                return tfm.decode_step(params, cfg, token, cache)
+                return tfm.decode_step(params, cfg, token, cache, mesh=mesh)
 
         # the decode hot loop donates the cache: the compiled step aliases
         # it in-place instead of copying (L,B,Hkv,C,hd) every token
@@ -300,7 +300,7 @@ class ServeEngine:
                 kw["embeds"] = embeds
             if enc_embeds is not None:
                 kw["enc_embeds"] = enc_embeds
-            return tfm.prefill(params, cfg, tokens, cache, **kw)
+            return tfm.prefill(params, cfg, tokens, cache, mesh=mesh, **kw)
 
         self._prefill = jax.jit(prefill, donate_argnums=(2,))
 
@@ -314,6 +314,9 @@ class ServeEngine:
         # -- continuous-batching state (built lazily by _ensure_slots) ----
         self.slot_cache: Optional[dict] = None
         self._chunk_jits: dict[int, Any] = {}
+        # last-position logits (rows, V) of the latest ``step`` or
+        # ``prefill_chunk``: what the slot path's greedy token came from
+        self.last_logits: Optional[jax.Array] = None
 
     # -- public API --------------------------------------------------------
     def prefill(self, tokens: jax.Array, cache: Optional[dict] = None,
@@ -360,7 +363,10 @@ class ServeEngine:
             self._spec, batch=1, per_slot=True)
         slot_shape = jax.eval_shape(
             lambda: tfm.init_cache(cfg, self._slot_spec))
-        self._slot_sh = cache_shardings(slot_shape, mesh)
+        self._slot_sh = slot_sh = cache_shardings(slot_shape, mesh)
+        self._req_sh = req_sh = cache_shardings(
+            jax.eval_shape(lambda: tfm.init_cache(cfg, self._req_spec)), mesh)
+        self._rep = rep = NamedSharding(mesh, P())
 
         if scfg.mode == "elk_stream":
             from repro.serve.stream import streaming_decode_slots
@@ -371,28 +377,37 @@ class ServeEngine:
                                               prefetch=scfg.prefetch_depth)
         else:
             def decode_slots(params, token, cache):
-                return tfm.decode_slots(params, cfg, token, cache)
+                return tfm.decode_slots(params, cfg, token, cache, mesh=mesh)
 
         def step(params, token, cache):
             logits, cache = decode_slots(params, token, cache)
-            return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), \
-                cache
+            last = logits[:, -1, :]
+            return jnp.argmax(last, axis=-1).astype(jnp.int32), last, cache
 
         self._step_slots = jax.jit(
             step,
-            in_shardings=(self.p_sh, self._tok_sh, self._slot_sh),
-            out_shardings=(self._tok_sh, self._slot_sh),
+            in_shardings=(self.p_sh, self._tok_sh, slot_sh),
+            out_shardings=(self._tok_sh,
+                           NamedSharding(mesh, P(batch_axes(mesh), "model")),
+                           slot_sh),
             donate_argnums=(2,),
         )
-        self._insert = jax.jit(tfm.cache_insert_slot, donate_argnums=(0,))
-        self._evict = jax.jit(tfm.cache_evict_slot, donate_argnums=(0,))
+        self._insert = jax.jit(tfm.cache_insert_slot,
+                               in_shardings=(slot_sh, rep, req_sh),
+                               out_shardings=slot_sh, donate_argnums=(0,))
+        self._evict = jax.jit(tfm.cache_evict_slot,
+                              in_shardings=(slot_sh, rep),
+                              out_shardings=slot_sh, donate_argnums=(0,))
         # extract reads the slot cache before _evict consumes it: no donate
-        self._extract = jax.jit(tfm.cache_extract_slot)
+        self._extract = jax.jit(tfm.cache_extract_slot,
+                                in_shardings=(slot_sh, rep),
+                                out_shardings=req_sh)
         self._req_cache0 = jax.jit(
-            lambda: tfm.init_cache(cfg, self._req_spec))
+            lambda: tfm.init_cache(cfg, self._req_spec),
+            out_shardings=req_sh)
         self.slot_cache = jax.jit(
             lambda: tfm.init_cache(cfg, self._slot_spec),
-            out_shardings=self._slot_sh)()
+            out_shardings=slot_sh)()
 
     def new_request_cache(self) -> dict:
         """Fresh single-request per-slot cache for chunked prefill."""
@@ -413,11 +428,17 @@ class ServeEngine:
             def chunk(params, toks, cache):
                 logits, cache = tfm.chunk_prefill(params, cfg, toks, cache,
                                                   mesh=mesh)
-                return (jnp.argmax(logits[:, -1, :], axis=-1)
-                        .astype(jnp.int32), cache)
+                last = logits[:, -1, :]
+                return (jnp.argmax(last, axis=-1).astype(jnp.int32), last,
+                        cache)
 
-            self._chunk_jits[t] = jax.jit(chunk, donate_argnums=(2,))
-        return self._chunk_jits[t](self.params, tokens, req_cache)
+            self._chunk_jits[t] = jax.jit(
+                chunk, in_shardings=(self.p_sh, self._rep, self._req_sh),
+                out_shardings=(self._rep, self._rep, self._req_sh),
+                donate_argnums=(2,))
+        tok, self.last_logits, req_cache = self._chunk_jits[t](
+            self.params, tokens, req_cache)
+        return tok, req_cache
 
     def insert_slot(self, slot: int, req_cache: dict) -> None:
         """Splice a prefilled request into ``slot`` of the running batch
@@ -450,7 +471,7 @@ class ServeEngine:
         (e.g. a prefix-store snapshot) is never aliased into the donated
         slot cache."""
         self._ensure_slots()
-        state = jax.tree.map(lambda a: jnp.array(a), state)
+        state = jax.device_put(state, self._req_sh, may_alias=False)
         self.slot_cache = self._insert(self.slot_cache,
                                        jnp.int32(slot), state)
 
@@ -468,6 +489,6 @@ class ServeEngine:
         tokens (slots,) int32 -> greedy next token per slot (slots,).  The
         slot cache advances in place (donated buffers, no copy)."""
         self._ensure_slots()
-        tok, self.slot_cache = self._step_slots(self.params, tokens,
-                                                self.slot_cache)
+        tok, self.last_logits, self.slot_cache = self._step_slots(
+            self.params, tokens, self.slot_cache)
         return tok

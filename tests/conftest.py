@@ -3,12 +3,12 @@ forces 512 host devices; smoke tests must see the real (1-device) CPU."""
 import jax
 import pytest
 
-from repro.launch.mesh import _make_mesh
+from repro.launch.mesh import make_local_mesh
 
 
 @pytest.fixture(scope="session")
 def mesh11():
-    return _make_mesh((1, 1), ("data", "model"))
+    return make_local_mesh()
 
 
 @pytest.fixture(scope="session")

@@ -160,6 +160,22 @@ def test_fused_mlp_matches_model_mlp(rng):
     assert float(jnp.max(jnp.abs(out - ref))) <= _tol(jnp.float32, ref)
 
 
+def test_fused_mlp_ff_block_divides_ff_and_fits_vmem():
+    """The FF block divides FF where a multiple of 128 can (no weight is
+    padded per call) and steps down until the double-buffered step fits
+    the VMEM budget; with no such divisor it keeps the requested block."""
+    from repro.kernels.fused_mlp.kernel import (_VMEM_BUDGET, _ff_block,
+                                                _vmem_bytes)
+    # qwen3_14b GLU (d 5120, ff 17408): the 512 slab divides and fits
+    assert _ff_block(512, 128, 5120, 17408, 5120, 2, 2) == 512
+    # d 16384 / ff 53248 (llama-3-405b widths): 512 and 256 overflow
+    bf = _ff_block(512, 128, 16384, 53248, 16384, 2, 2)
+    assert bf == 128 and 53248 % bf == 0
+    assert _vmem_bytes(128, bf, 16384, 16384, 2, 2) <= _VMEM_BUDGET
+    assert _vmem_bytes(128, 256, 16384, 16384, 2, 2) > _VMEM_BUDGET
+    assert _ff_block(128, 32, 96, 200, 96, 2, 4) == 128
+
+
 def test_vmem_plan_within_budget():
     from repro.core.integration import vmem_plan
     plan = vmem_plan(8192, 8192, 8192)

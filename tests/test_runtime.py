@@ -20,6 +20,39 @@ from repro.optim.adamw import AdamWConfig
 from repro.serve.engine import ServeConfig, ServeEngine
 from repro.train.trainer import Trainer, TrainerConfig
 
+_SRC = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "src")
+_FOUR_DEVICE_SERVE = """
+import dataclasses, json
+import jax, numpy as np
+from repro.configs import get_smoke_config
+from repro.kernels.dispatch import force_kernels
+from repro.launch.mesh import make_local_mesh
+from repro.models import transformer as T
+from repro.serve.batcher import ContinuousBatcher, make_trace
+from repro.serve.engine import ServeConfig, ServeEngine
+cfg = dataclasses.replace(get_smoke_config("qwen3_14b"),
+                          dtype="float32", param_dtype="float32")
+params = T.init_params(jax.random.PRNGKey(0), cfg)
+prompts = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0,
+                             cfg.vocab_size)
+trace = make_trace(6, vocab_size=cfg.vocab_size, seed=0)
+out = {}
+with force_kernels():
+    for name, mesh, mode, depth in (
+            ("gspmd x1", make_local_mesh(), "gspmd", 1),
+            ("elk_stream x4 p1", make_local_mesh(4, 1), "elk_stream", 1),
+            ("elk_stream x4 p2", make_local_mesh(4, 1), "elk_stream", 2)):
+        eng = ServeEngine(cfg, mesh, params, ServeConfig(
+            batch=4, cache_capacity=64, mode=mode, prefetch_depth=depth,
+            prefill_chunk=8))
+        out[name] = {
+            "generate": np.asarray(eng.generate(prompts, steps=5)).tolist(),
+            "slots": {c.rid: c.tokens.tolist()
+                      for c in ContinuousBatcher(eng).run(trace)}}
+print(json.dumps(out))
+"""
+
 
 def _mk_trainer(wd, mesh, steps=20, ckpt_every=5, failure_hook=None,
                 **tkw):
@@ -186,6 +219,75 @@ class TestServe:
             if ref is None:
                 ref = out
             np.testing.assert_array_equal(ref, out)
+
+
+    def test_stream_over_four_devices_equals_one(self):
+        """elk_stream with weights sharded over a (data=4, model=1) mesh —
+        the gather-ahead window and the per-shard fused-MLP kernel — serves
+        the same greedy tokens as gspmd on one device, lock-step and
+        through the continuous batcher.  Four host devices exist only in a
+        fresh process; float32 weights keep re-associated sums off argmax
+        ties."""
+        import json
+        import subprocess
+        import sys
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4",
+                   PYTHONPATH=os.pathsep.join(
+                       [_SRC, os.environ.get("PYTHONPATH", "")]))
+        res = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SERVE],
+                             env=env, capture_output=True, text=True,
+                             timeout=600)
+        assert res.returncode == 0, res.stderr[-4000:]
+        out = json.loads(res.stdout.strip().splitlines()[-1])
+        assert out["gspmd x1"]["slots"], out
+        for name in ("elk_stream x4 p1", "elk_stream x4 p2"):
+            assert out[name] == out["gspmd x1"], name
+
+
+class TestLaunch:
+    def test_local_mesh_never_shrinks(self):
+        from repro.launch.mesh import make_local_mesh
+        n = len(jax.devices())
+        assert make_local_mesh(n, 1).devices.size == n
+        with pytest.raises(ValueError, match="devices"):
+            make_local_mesh(n + 1, 1)
+
+    def test_compile_cache_defaults_to_checkout(self, monkeypatch):
+        from repro.launch.compile_cache import CHECKOUT_CACHE, \
+            use_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            assert use_compile_cache() == str(CHECKOUT_CACHE)
+            assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+        assert str(CHECKOUT_CACHE.parent) == os.path.dirname(_SRC)
+
+    def test_compile_cache_follows_env(self, tmp_path):
+        """With JAX_COMPILATION_CACHE_DIR set, compiled entries land there
+        and the helper sets no directory of its own."""
+        import subprocess
+        import sys
+        code = (
+            "import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import use_compile_cache\n"
+            "d = use_compile_cache()\n"
+            "assert jax.config.jax_compilation_cache_dir == d, d\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0)\n"
+            "jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(7)).block_until_ready()"
+            "\nprint(d)\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+                   PYTHONPATH=os.pathsep.join(
+                       [_SRC, os.environ.get("PYTHONPATH", "")]))
+        res = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert res.returncode == 0, res.stderr[-4000:]
+        assert res.stdout.strip() == str(tmp_path / "cc")
+        assert any((tmp_path / "cc").iterdir())
 
 
 class TestCompression:
