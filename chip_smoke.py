@@ -43,9 +43,14 @@ SEED = 0
 # Tolerances, as max |got - ref| over max |ref|.  bf16 keeps 8 mantissa
 # bits (relative step 2^-8 = 0.0039): the kernel keeps its intermediate in
 # fp32 where the reference rounds it to bf16 (a few steps); two serving
-# paths re-associate bf16 sums through LAYERS layers (about a dozen).
+# paths re-associate bf16 sums through LAYERS layers.  On a TPU v5e the
+# logits differed by 6.8e-3 (slot path vs lock-step) and 1.1e-2 (four
+# chips vs one), so LOGIT_TOL sits a little under twice the larger.  A
+# greedy token may differ only where the reference's top two logits are
+# within TIE_TOL of max |ref| (four bf16 steps): a near tie.
 MLP_TOL = 2e-2
-LOGIT_TOL = 5e-2
+LOGIT_TOL = 2e-2
+TIE_TOL = 4 * 2.0 ** -8
 
 
 def require_tpu():
@@ -64,17 +69,25 @@ def model_config():
     return dataclasses.replace(get_config("qwen3_14b"), num_layers=LAYERS)
 
 
+def serve_config(cfg, mesh):
+    """ServeConfig from the ELK plan for ``mesh``, as ``launch/serve.py``
+    builds it."""
+    from repro.serve.engine import elk_serve_config
+
+    return elk_serve_config(cfg, batch=SLOTS, cache_capacity=CAPACITY,
+                            num_chips=mesh.size, mesh=mesh)
+
+
 def build_engine(cfg, mesh):
-    """ServeConfig from the ELK plan, as ``launch/serve.py`` builds it, and
-    the weights built on the device straight into the engine's shardings."""
+    """The engine for ``serve_config``, its weights built on the device
+    straight into the engine's shardings."""
     import jax
 
     from repro.distributed.sharding import init_sharded
     from repro.models import transformer as tfm
-    from repro.serve.engine import ServeEngine, elk_serve_config
+    from repro.serve.engine import ServeEngine
 
-    scfg = elk_serve_config(cfg, batch=SLOTS, cache_capacity=CAPACITY,
-                            num_chips=mesh.size)
+    scfg = serve_config(cfg, mesh)
     params = init_sharded(lambda: tfm.init_params(
         jax.random.PRNGKey(SEED), cfg), mesh, fsdp=scfg.mode == "elk_stream")
     eng = ServeEngine(cfg, mesh, params, scfg)
@@ -125,16 +138,16 @@ def probe_group(trace):
     return [r.rid for r in group], np.stack([r.prompt for r in group])
 
 
-def slot_probe(eng, prompts, forced):
+def slot_probe(eng, prompts, forced, budget=None):
     """Last-position logits of the slot path: each prompt prefilled in
-    power-of-two chunks within the engine's admission budget, spliced into
-    slot r, then ``PROBE_STEPS`` decode steps fed the ``forced`` tokens.
-    Returns float32 (rows, PROBE_STEPS + 1, V)."""
+    power-of-two chunks within ``budget`` (default: the engine's admission
+    budget), spliced into slot r, then ``PROBE_STEPS`` decode steps fed the
+    ``forced`` tokens.  Returns float32 (rows, PROBE_STEPS + 1, V)."""
     import jax.numpy as jnp
     import numpy as np
 
     rows, length = prompts.shape
-    budget = min(eng.scfg.prefill_chunk, eng.scfg.cache_capacity)
+    budget = min(budget or eng.scfg.prefill_chunk, eng.scfg.cache_capacity)
     out = []
     for r in range(rows):
         rc, off = eng.new_request_cache(), 0
@@ -174,7 +187,8 @@ def lockstep_probe(eng, prompts, forced):
 
 def compare_logits(label, got, ref, tol):
     """max |got - ref| / max |ref| within ``tol``; an argmax may differ
-    only where the reference's own top two are that close (a near tie)."""
+    only where the reference's own top two are within ``TIE_TOL`` of
+    max |ref| (a near tie)."""
     import numpy as np
 
     scale = float(np.max(np.abs(ref)))
@@ -183,11 +197,13 @@ def compare_logits(label, got, ref, tol):
     agree = int((a_got == a_ref).sum())
     margin = (np.take_along_axis(ref, a_ref[..., None], -1)
               - np.take_along_axis(ref, a_got[..., None], -1))[..., 0]
-    ties_ok = bool(np.all(margin <= tol * scale))
+    widest = float(margin.max()) / scale
+    ties_ok = widest <= TIE_TOL
     ok = rel <= tol and ties_ok
     print(f"[{label}] last-position logits: max|d|/max|ref| = {rel} "
           f"(tolerance {tol}); greedy token agrees at {agree}/{a_ref.size} "
-          f"positions; every disagreement a near tie: {ties_ok} -> "
+          f"positions; widest reference margin at a disagreement "
+          f"{widest} of max|ref| (near tie up to {TIE_TOL}) -> "
           f"{'PASS' if ok else 'FAIL'}", flush=True)
     return ok
 
@@ -278,8 +294,9 @@ def describe(cfg, eng):
           f"depth cut {full.num_layers} -> {cfg.num_layers} layers; "
           f"{nbytes} weight bytes; mode {eng.scfg.mode}, {eng.scfg.slots} "
           f"slots x {eng.scfg.cache_capacity}-token KV ring, prefill chunk "
-          f"{eng.scfg.prefill_chunk}, prefetch depth "
-          f"{eng.scfg.prefetch_depth}", flush=True)
+          f"{eng.scfg.prefill_chunk}"
+          + (f", prefetch depth {eng.scfg.prefetch_depth}"
+             if eng.scfg.mode == "elk_stream" else ""), flush=True)
 
 
 def one_chip(dev):
@@ -326,7 +343,7 @@ def one_chip(dev):
     checks["memory"] = peak is not None and peak < 16e9
     print(f"[memory] peak_bytes_in_use {peak} of bytes_limit {limit} "
           f"(must stay under 16e9)", flush=True)
-    return checks
+    return checks, mesh.size
 
 
 def four_chips(dev):
@@ -338,28 +355,32 @@ def four_chips(dev):
     cfg = model_config()
     trace = make_requests(cfg)
     rids, prompts = probe_group(trace)
+    one, four = make_local_mesh(), make_local_mesh(4, 1)
+    # both probes prefill in the same chunks, so the logits differ only by
+    # the serving path (gather window over four chips vs resident weights)
+    chunk = min(serve_config(cfg, m).prefill_chunk for m in (one, four))
 
     def run(mesh, label, forced=None):
         eng = build_engine(cfg, mesh)
-        if forced is None:
-            describe(cfg, eng)
+        print(f"[{label}]", end=" ")
+        describe(cfg, eng)
         ok, tokens = serve(eng, trace, label)
         if forced is None:
             forced = np.stack([tokens[r][-NEW_TOKENS:] for r in rids])
-        logits = slot_probe(eng, prompts, forced)
+        logits = slot_probe(eng, prompts, forced, chunk)
         peak = [peak_bytes(d)[0] for d in mesh.devices.flat]
         print(f"[{label}] peak_bytes_in_use per device {peak}", flush=True)
         return ok, tokens, forced, logits
 
-    ok1, ref_tokens, forced, ref_logits = run(make_local_mesh(), "1 chip")
+    ok1, ref_tokens, forced, ref_logits = run(one, "1 chip")
     gc.collect()
     jax.clear_caches()
-    ok4, tokens, _, logits = run(make_local_mesh(4, 1), "4 chips", forced)
+    ok4, tokens, _, logits = run(four, "4 chips", forced)
     checks = {"served 1 chip": ok1, "served 4 chips": ok4}
     checks["logits"] = compare_logits("4 chips vs 1 chip", logits,
                                       ref_logits, LOGIT_TOL)
     token_agreement("4 chips vs 1 chip", tokens, ref_tokens)
-    return checks
+    return checks, four.size
 
 
 def main() -> None:
@@ -377,7 +398,8 @@ def main() -> None:
     cache_dir = use_compile_cache()
     print(f"device: {dev.device_kind}, {len(jax.devices())} visible; "
           f"compile cache {cache_dir}", flush=True)
-    checks = four_chips(dev) if args.chips == 4 else one_chip(dev)
+    # count: the chips the checked phase ran on, not the chips visible
+    checks, count = four_chips(dev) if args.chips == 4 else one_chip(dev)
     entries = (sum(1 for _ in Path(cache_dir).iterdir())
                if Path(cache_dir).is_dir() else 0)
     print(f"compile cache: {entries} entries in {cache_dir}", flush=True)
@@ -386,7 +408,7 @@ def main() -> None:
         sys.exit(f"chip_smoke: failed checks: {failed}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
-        "count": len(jax.devices())}}))
+        "count": count}}))
 
 
 if __name__ == "__main__":

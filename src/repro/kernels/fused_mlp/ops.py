@@ -43,32 +43,19 @@ def fused_mlp(x: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
 
 
 def _per_shard(call, mesh: Mesh, x, w_up, w_down, w_gate, b_up, b_down):
-    """``shard_map`` the kernel: rows split over the batch axes (pod, data)
-    where the leading dim divides, the FF dim over ``model`` Megatron-style
-    (column-parallel up/gate, row-parallel down, one psum) where it
-    divides; everything else is gathered to each shard."""
+    """``shard_map`` the kernel with rows split over the batch axes (pod,
+    data) where the leading dim divides; the weights are gathered whole to
+    every shard."""
     bp = tuple(ax for ax in ("pod", "data") if ax in mesh.shape)
     dp = math.prod(mesh.shape[ax] for ax in bp)
     rows = bp if bp and x.shape[0] % dp == 0 else None
-    tp = mesh.shape.get("model", 1)
-    ff = "model" if tp > 1 and w_up.shape[1] % tp == 0 else None
     x_spec = P(rows, *([None] * (x.ndim - 1)))
-    bias_after = b_down if ff else None     # added once, after the psum
+    names = ("w_up", "w_down", "w_gate", "b_up", "b_down")
+    given = {k: v for k, v in zip(names, (w_up, w_down, w_gate, b_up, b_down))
+             if v is not None}
 
-    names = ("x", "w_up", "w_down", "w_gate", "b_up", "b_down")
-    specs = dict(x=x_spec, w_up=P(None, ff), w_down=P(ff, None),
-                 w_gate=P(None, ff), b_up=P(ff), b_down=P())
-    vals = dict(zip(names, (x, w_up, w_down, w_gate, b_up,
-                            None if ff else b_down)))
-    given = {k: v for k, v in vals.items() if v is not None}
+    def body(x, given):
+        return call(x, *(given.get(k) for k in names))
 
-    def body(given):
-        out = call(*(given.get(k) for k in names))
-        return jax.lax.psum(out, ff) if ff else out
-
-    out = jax.shard_map(body, mesh=mesh,
-                        in_specs=({k: specs[k] for k in given},),
-                        out_specs=x_spec, check_vma=False)(given)
-    if bias_after is not None:
-        out = out + bias_after.astype(out.dtype)
-    return out
+    return jax.shard_map(body, mesh=mesh, in_specs=(x_spec, P()),
+                         out_specs=x_spec, check_vma=False)(x, given)

@@ -195,8 +195,10 @@ def main() -> None:
         scfg = elk_serve_config(cfg, batch=args.batch,
                                 cache_capacity=args.cache,
                                 kv_dtype=args.kv_dtype,
-                                pipeline=args.pipeline_pod > 0, pod=pod)
-        msg = (f"ELK scheduler: prefetch_depth={scfg.prefetch_depth} "
+                                pipeline=args.pipeline_pod > 0, pod=pod,
+                                mesh=mesh)
+        msg = (f"ELK scheduler: mode={scfg.mode} "
+               f"prefetch_depth={scfg.prefetch_depth} "
                f"prefill_chunk={scfg.prefill_chunk}")
         if scfg.steady_interval_s:
             msg += (f" steady_interval="

@@ -30,6 +30,7 @@ returned one.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import jax
@@ -161,7 +162,8 @@ PREFILL_SAT = 128   # prompt tokens one weight pass saturates: the chunk
 def elk_serve_config(cfg: ModelConfig, *, batch: int, cache_capacity: int,
                      kv_dtype: str = "bfloat16", num_chips: int = 256,
                      design: str = "ELK-Full", pipeline: bool = False,
-                     pod=None, role: str = "mixed") -> ServeConfig:
+                     pod=None, role: str = "mixed",
+                     mesh=None) -> ServeConfig:
     """ServeConfig with the serving knobs chosen by the ELK scheduler.
 
     ``pod_plan`` reads the process-level plan cache (DESIGN.md §2), so this
@@ -195,6 +197,14 @@ def elk_serve_config(cfg: ModelConfig, *, batch: int, cache_capacity: int,
     to it) while the plan's full oversubscription K stays, maximizing
     slots x oversub.  ``mixed`` (the default) is byte-identical to the
     pre-fleet behaviour.
+
+    ``mesh`` is the local mesh the engine will run on.  When its batch
+    axes hold one device every block is resident and there is nothing to
+    gather, so the mode is ``gspmd`` (a window would only copy blocks the
+    layer scan reads in place) and, outside a pipeline plan, the chunk
+    opens to ``PREFILL_SAT``: with no window traffic to hide behind, a
+    tick's chunk is bounded by what one resident weight pass saturates.
+    Without a mesh the config is the plan's, as before.
     """
     from repro.core.integration import pod_plan
 
@@ -233,9 +243,13 @@ def elk_serve_config(cfg: ModelConfig, *, batch: int, cache_capacity: int,
                      num_chips=num_chips, design=design,
                      mode="hybrid" if pipeline else "flat", chip=pod)
     depth = max(knobs.prefetch_depth, 1)
+    resident = mesh is not None and math.prod(
+        mesh.shape[ax] for ax in batch_axes(mesh)) == 1
     if pipeline and knobs.microbatch > 0:
         per_interval = max(knobs.microbatch * max(knobs.microbatches, 1), 16)
         chunk = min(per_interval, PREFILL_SAT, cache_capacity)
+    elif resident:
+        chunk = min(PREFILL_SAT, cache_capacity)
     else:
         chunk = min(max(16, min(16 * depth, PREFILL_SAT)), cache_capacity)
     if role == "prefill":
@@ -247,7 +261,8 @@ def elk_serve_config(cfg: ModelConfig, *, batch: int, cache_capacity: int,
         # minimal chunk (shed/local work) and the full residency budget
         chunk = min(16, cache_capacity)
     return ServeConfig(batch=batch, cache_capacity=cache_capacity,
-                       mode="elk_stream", prefetch_depth=depth,
+                       mode="gspmd" if resident else "elk_stream",
+                       prefetch_depth=depth,
                        kv_dtype=kv_dtype, prefill_chunk=chunk,
                        steady_interval_s=knobs.interval_s,
                        oversub=oversub, slot_spill_s=slot_spill_s,

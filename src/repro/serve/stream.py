@@ -64,12 +64,7 @@ def streaming_decoder(params, cfg: ModelConfig, x, ctx, cache,
     """Drop-in replacement for ``transformer._run_decoder`` that streams
     block weights with an ELK gather-ahead window of depth ``prefetch``.
 
-    Returns (x, new_cache_layers) with the same contract.  With a data
-    axis of one there is nothing to gather — every block is resident — so
-    this is ``_run_decoder`` itself: a window would only copy ``prefetch``
-    blocks per layer that the scan can read in place."""
-    if mesh.shape.get("data", 1) == 1:
-        return tfm._run_decoder(params, cfg, x, ctx, cache)
+    Returns (x, new_cache_layers) with the same contract."""
     prefix, period, n_blocks = tfm.block_structure(cfg)
     kinds = tfm.layer_kinds(cfg)
     spec = tfm.attn_spec(cfg)

@@ -386,6 +386,21 @@ class TestRoleSizing:
             elk_serve_config(cfg, batch=2, cache_capacity=256,
                              role="router")
 
+    def test_mode_follows_the_local_mesh(self, mesh11):
+        """One device on the batch axes: nothing to gather, so gspmd with
+        the saturating chunk.  A wider data axis keeps the plan's window."""
+        from jax.sharding import AbstractMesh
+        cfg = get_smoke_config("qwen3_14b")
+        plan = elk_serve_config(cfg, batch=2, cache_capacity=256)
+        one = elk_serve_config(cfg, batch=2, cache_capacity=256, mesh=mesh11)
+        four = elk_serve_config(cfg, batch=2, cache_capacity=256,
+                                mesh=AbstractMesh((4, 1), ("data", "model")))
+        assert (one.mode, one.prefill_chunk) == ("gspmd", PREFILL_SAT)
+        assert four == plan and plan.mode == "elk_stream"
+        dec = elk_serve_config(cfg, batch=2, cache_capacity=256,
+                               role="decode", mesh=mesh11)
+        assert (dec.mode, dec.prefill_chunk) == ("gspmd", 16)
+
 
 class TestFleetSweep:
     def test_smoke_rows_and_disagg_verdict(self):

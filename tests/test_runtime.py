@@ -23,7 +23,7 @@ from repro.train.trainer import Trainer, TrainerConfig
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src")
 _FOUR_DEVICE_SERVE = """
-import dataclasses, json
+import dataclasses, json, sys
 import jax, numpy as np
 from repro.configs import get_smoke_config
 from repro.kernels.dispatch import force_kernels
@@ -31,27 +31,67 @@ from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as T
 from repro.serve.batcher import ContinuousBatcher, make_trace
 from repro.serve.engine import ServeConfig, ServeEngine
-cfg = dataclasses.replace(get_smoke_config("qwen3_14b"),
-                          dtype="float32", param_dtype="float32")
-params = T.init_params(jax.random.PRNGKey(0), cfg)
-prompts = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0,
-                             cfg.vocab_size)
-trace = make_trace(6, vocab_size=cfg.vocab_size, seed=0)
-out = {}
-with force_kernels():
-    for name, mesh, mode, depth in (
-            ("gspmd x1", make_local_mesh(), "gspmd", 1),
-            ("elk_stream x4 p1", make_local_mesh(4, 1), "elk_stream", 1),
-            ("elk_stream x4 p2", make_local_mesh(4, 1), "elk_stream", 2)):
-        eng = ServeEngine(cfg, mesh, params, ServeConfig(
-            batch=4, cache_capacity=64, mode=mode, prefetch_depth=depth,
-            prefill_chunk=8))
-        out[name] = {
-            "generate": np.asarray(eng.generate(prompts, steps=5)).tolist(),
+
+def serve(cfg, params, mesh, mode, depth, cap, prompt_len, steps):
+    eng = ServeEngine(cfg, mesh, params, ServeConfig(
+        batch=4, cache_capacity=cap, mode=mode, prefetch_depth=depth,
+        prefill_chunk=8))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (4, prompt_len), 0,
+                                 cfg.vocab_size)
+    trace = make_trace(6, vocab_size=cfg.vocab_size, seed=0)
+    return {"generate": np.asarray(eng.generate(prompts, steps)).tolist(),
             "slots": {c.rid: c.tokens.tolist()
                       for c in ContinuousBatcher(eng).run(trace)}}
+
+def per_shard_mlp(gated, rows):
+    from repro.kernels.fused_mlp.ops import fused_mlp
+    from repro.kernels.fused_mlp.ref import fused_mlp_ref
+    k = jax.random.split(jax.random.PRNGKey(2), 6)
+    d, ff = 128, 256
+    x = jax.random.normal(k[0], (rows, d))
+    w = {"w_up": jax.random.normal(k[1], (d, ff)) * d ** -0.5,
+         "w_down": jax.random.normal(k[2], (ff, d)) * ff ** -0.5}
+    if gated:
+        w["w_gate"] = jax.random.normal(k[3], (d, ff)) * d ** -0.5
+    else:
+        w["b_up"] = jax.random.normal(k[4], (ff,))
+        w["b_down"] = jax.random.normal(k[5], (d,))
+    got = jax.jit(lambda x, w: fused_mlp(x, act="gelu", force_kernel=True,
+                                         mesh=make_local_mesh(4, 1), **w))(x, w)
+    ref = fused_mlp_ref(x, act="gelu", **w)
+    return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+
+out, refs = {}, {}
+out["mlp"] = {f"{g} {m}": per_shard_mlp(g == "gated", m)
+              for g, m in (("gated", 8), ("ungated", 8), ("ungated", 6))}
+with force_kernels():
+    for name, (arch, depth, cap, prompt_len, steps) in json.loads(
+            sys.argv[1]).items():
+        cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32",
+                                  param_dtype="float32")
+        params = T.init_params(jax.random.PRNGKey(0), cfg)
+        shape = (arch, cap, prompt_len, steps)
+        if shape not in refs:
+            refs[shape] = serve(cfg, params, make_local_mesh(), "gspmd", 1,
+                                cap, prompt_len, steps)
+        out[name] = {"gspmd x1": refs[shape],
+                     "elk_stream x4": serve(cfg, params, make_local_mesh(4, 1),
+                                            "elk_stream", depth, cap,
+                                            prompt_len, steps)}
 print(json.dumps(out))
 """
+# name -> (arch, prefetch depth, cache capacity, prompt length, steps):
+# prefetch depths 1/2/4 on the dense model, one arch per other cache family
+# (RWKV state, hybrid attention+SSM, MoE), and SWA under ring wraparound
+_FOUR_DEVICE_CASES = {
+    "qwen3 p1": ("qwen3_14b", 1, 64, 8, 5),
+    "qwen3 p2": ("qwen3_14b", 2, 64, 8, 5),
+    "qwen3 p4": ("qwen3_14b", 4, 64, 8, 5),
+    "rwkv6": ("rwkv6_7b", 2, 64, 8, 5),
+    "hymba": ("hymba_1_5b", 2, 64, 8, 5),
+    "llama4 moe": ("llama4_maverick_400b_a17b", 2, 64, 8, 5),
+    "h2o_danube wraparound": ("h2o_danube_1_8b", 2, 16, 12, 10),
+}
 
 
 def _mk_trainer(wd, mesh, steps=20, ckpt_every=5, failure_hook=None,
@@ -221,13 +261,10 @@ class TestServe:
             np.testing.assert_array_equal(ref, out)
 
 
-    def test_stream_over_four_devices_equals_one(self):
-        """elk_stream with weights sharded over a (data=4, model=1) mesh —
-        the gather-ahead window and the per-shard fused-MLP kernel — serves
-        the same greedy tokens as gspmd on one device, lock-step and
-        through the continuous batcher.  Four host devices exist only in a
-        fresh process; float32 weights keep re-associated sums off argmax
-        ties."""
+    @pytest.fixture(scope="class")
+    def four_device_serve(self):
+        """Every ``_FOUR_DEVICE_CASES`` entry served in one fresh process
+        with four host devices (they exist only in a new process)."""
         import json
         import subprocess
         import sys
@@ -235,14 +272,31 @@ class TestServe:
                    XLA_FLAGS="--xla_force_host_platform_device_count=4",
                    PYTHONPATH=os.pathsep.join(
                        [_SRC, os.environ.get("PYTHONPATH", "")]))
-        res = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SERVE],
-                             env=env, capture_output=True, text=True,
-                             timeout=600)
+        res = subprocess.run(
+            [sys.executable, "-c", _FOUR_DEVICE_SERVE,
+             json.dumps(_FOUR_DEVICE_CASES)],
+            env=env, capture_output=True, text=True, timeout=900)
         assert res.returncode == 0, res.stderr[-4000:]
-        out = json.loads(res.stdout.strip().splitlines()[-1])
+        return json.loads(res.stdout.strip().splitlines()[-1])
+
+    @pytest.mark.parametrize("case", list(_FOUR_DEVICE_CASES))
+    def test_stream_over_four_devices_equals_one(self, case,
+                                                 four_device_serve):
+        """elk_stream with weights sharded over a (data=4, model=1) mesh —
+        the gather-ahead window and the per-shard fused-MLP kernel — serves
+        the same greedy tokens as gspmd on one device, lock-step and
+        through the continuous batcher.  float32 weights keep re-associated
+        sums off argmax ties."""
+        out = four_device_serve[case]
         assert out["gspmd x1"]["slots"], out
-        for name in ("elk_stream x4 p1", "elk_stream x4 p2"):
-            assert out[name] == out["gspmd x1"], name
+        assert out["elk_stream x4"] == out["gspmd x1"], case
+
+    @pytest.mark.parametrize("case", ["gated 8", "ungated 8", "ungated 6"])
+    def test_fused_mlp_per_shard_matches_ref(self, case, four_device_serve):
+        """The fused-MLP kernel under ``shard_map`` on four devices (rows
+        split where 4 divides them, gathered where not; fc biases given)
+        matches the composed reference."""
+        assert four_device_serve["mlp"][case] <= 1e-5, four_device_serve["mlp"]
 
 
 class TestLaunch:
