@@ -1,0 +1,22 @@
+"""Published peaks of one chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (cloud.google.com/tpu/docs/v5e):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.  A device
+that is not in the table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9,
+                    "source": "Google Cloud documentation, TPU v5e"},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
