@@ -21,6 +21,12 @@ through the scan as xs/ys slices.  KV caches are ring buffers of capacity
 ``C``: exact attention while ``pos < C`` and sliding-window semantics
 beyond — full-attention serving sizes ``C = seq_len``, SWA archs size
 ``C = window`` (how hymba/danube hold ``long_500k`` state in O(window)).
+
+Every op carries a named scope in its metadata, so a profiler trace can
+split a step by them: ``layer`` (one decoder layer), ``attn``, ``mlp``,
+``head``, and ``kv_ring`` for every move of the cache (the ring stacked
+into and out of the layer scan, the per-layer slice, the merge, the
+position tags and the write of new K/V).
 """
 
 from __future__ import annotations
@@ -265,6 +271,7 @@ def _project_qkv(x, p, cfg: ModelConfig):
     return q, k, v
 
 
+@jax.named_scope("attn")
 def _self_attention_full(x, p, cfg: ModelConfig, spec: AttnSpec,
                          sin, cos, positions, mesh=None, layout="tp"):
     """Training/prefill attention over the whole sequence.
@@ -305,6 +312,7 @@ def _self_attention_full(x, p, cfg: ModelConfig, spec: AttnSpec,
     return linear(out, p["w_o"]), k, v
 
 
+@jax.named_scope("attn")
 def _self_attention_decode(x, p, cfg: ModelConfig, spec: AttnSpec,
                            k_cache, v_cache, kq_scales, slot_pos, pos):
     """x: (B,1,d) one token at absolute position ``pos`` against the ring
@@ -319,18 +327,20 @@ def _self_attention_decode(x, p, cfg: ModelConfig, spec: AttnSpec,
     if kq_scales is not None:
         kq, ks = _quantize_kv(k_new)
         vq, vs = _quantize_kv(v_new)
-        k_cache = lax.dynamic_update_slice_in_dim(k_cache, kq, slot, axis=2)
-        v_cache = lax.dynamic_update_slice_in_dim(v_cache, vq, slot, axis=2)
-        k_sc = lax.dynamic_update_slice_in_dim(kq_scales[0], ks, slot, axis=2)
-        v_sc = lax.dynamic_update_slice_in_dim(kq_scales[1], vs, slot, axis=2)
+        with jax.named_scope("kv_ring"):
+            k_cache = lax.dynamic_update_slice_in_dim(k_cache, kq, slot, 2)
+            v_cache = lax.dynamic_update_slice_in_dim(v_cache, vq, slot, 2)
+            k_sc = lax.dynamic_update_slice_in_dim(kq_scales[0], ks, slot, 2)
+            v_sc = lax.dynamic_update_slice_in_dim(kq_scales[1], vs, slot, 2)
         k = _dequantize_kv(k_cache, k_sc)
         v = _dequantize_kv(v_cache, v_sc)
         new_scales = (k_sc, v_sc)
     else:
-        k_cache = lax.dynamic_update_slice_in_dim(
-            k_cache, k_new.astype(k_cache.dtype), slot, axis=2)
-        v_cache = lax.dynamic_update_slice_in_dim(
-            v_cache, v_new.astype(v_cache.dtype), slot, axis=2)
+        with jax.named_scope("kv_ring"):
+            k_cache = lax.dynamic_update_slice_in_dim(
+                k_cache, k_new.astype(k_cache.dtype), slot, axis=2)
+            v_cache = lax.dynamic_update_slice_in_dim(
+                v_cache, v_new.astype(v_cache.dtype), slot, axis=2)
         k, v = k_cache, v_cache
         new_scales = None
     bias = attn_mask_bias(spec, jnp.full((1,), pos), slot_pos)
@@ -339,6 +349,7 @@ def _self_attention_decode(x, p, cfg: ModelConfig, spec: AttnSpec,
     return linear(out, p["w_o"]), k_cache, v_cache, new_scales
 
 
+@jax.named_scope("attn")
 def _self_attention_slots(x, p, cfg: ModelConfig, spec: AttnSpec,
                           k_cache, v_cache, kq_scales, slot_pos, positions):
     """Per-slot cached attention: every batch row sits at its own absolute
@@ -359,6 +370,7 @@ def _self_attention_slots(x, p, cfg: ModelConfig, spec: AttnSpec,
     rows = jnp.arange(b)[:, None]                      # (B,1)
     slots = positions % c                              # (B,T)
 
+    @jax.named_scope("kv_ring")
     def scatter(buf, val):                             # val: (B,H,T,*)
         return buf.at[rows, :, slots, :].set(
             val.astype(buf.dtype).transpose(0, 2, 1, 3),
@@ -391,6 +403,7 @@ def _self_attention_slots(x, p, cfg: ModelConfig, spec: AttnSpec,
     return linear(out, p["w_o"]), k_cache, v_cache, new_scales
 
 
+@jax.named_scope("attn")
 def _cross_attention(x, p, cfg: ModelConfig, ck, cv, mesh=None):
     """Cross-attention, q-chunked like self-attention: the unchunked
     (B,H,Sq,Senc) fp32 score tensor dominated whisper training memory
@@ -409,6 +422,7 @@ def _cross_attention(x, p, cfg: ModelConfig, ck, cv, mesh=None):
     return linear(out, p["w_o"])
 
 
+@jax.named_scope("mlp")
 def _ffn(x, p, cfg: ModelConfig, kind: str, mode: str = "train",
          mesh=None):
     if kind == "moe":
@@ -423,6 +437,7 @@ def _ffn(x, p, cfg: ModelConfig, kind: str, mode: str = "train",
     return mlp(x, p["mlp"], cfg, mesh)
 
 
+@jax.named_scope("layer")
 def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,
                    ctx: dict, layer_cache: Optional[dict]):
     """Apply one decoder layer.  Returns (x, updated layer cache slices)."""
@@ -490,6 +505,7 @@ def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,
     return x, new_cache
 
 
+@jax.named_scope("kv_ring")
 def _fill_ring(buf: jax.Array, val: jax.Array, total_seq: int) -> jax.Array:
     """Write a prefill's last-C tokens into the ring with the true ring
     layout: position ``p`` lands at slot ``p % C`` (so later decode steps
@@ -517,6 +533,7 @@ def _embed(params, cfg: ModelConfig, tokens, embeds):
     return x
 
 
+@jax.named_scope("head")
 def _logits(params, cfg: ModelConfig, x):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -580,6 +597,7 @@ def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
     spec = attn_spec(cfg)
     new_layers: list[Optional[dict]] = [None] * cfg.num_layers
 
+    @jax.named_scope("kv_ring")
     def cache_slice(li):
         if cache is None:
             return None
@@ -600,6 +618,7 @@ def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
     if n_blocks:
         xs_cache = None
         if cache is not None:
+            @jax.named_scope("kv_ring")
             def stack_blocks(arr):
                 L = arr.shape[0]
                 body = arr[prefix:prefix + n_blocks * period]
@@ -617,13 +636,15 @@ def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
             outs = []
             for slot in range(period):
                 kind = kinds[prefix + slot]
-                lc = (jax.tree.map(lambda a: a[slot], bcache)
-                      if bcache is not None else None)
+                with jax.named_scope("kv_ring"):
+                    lc = (jax.tree.map(lambda a: a[slot], bcache)
+                          if bcache is not None else None)
                 x, nc = _decoder_layer(x, bparams[slot], cfg, kind,
                                        spec, ctx, lc)
                 outs.append(nc)
-            ys = (jax.tree.map(lambda *zs: jnp.stack(zs), *outs)
-                  if outs[0] else None)
+            with jax.named_scope("kv_ring"):
+                ys = (jax.tree.map(lambda *zs: jnp.stack(zs), *outs)
+                      if outs[0] else None)
             x = _seq_shard_hidden(x, ctx.get("mesh"))
             return x, ys
 
@@ -644,14 +665,16 @@ def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
             x, ys = lax.scan(block_body, x, (bparams, xs_cache))
         if ys is not None:
             # unstack ys back into per-layer entries
-            flat = jax.tree.map(
-                lambda a: a.reshape((n_blocks * period,) + a.shape[2:]), ys)
-            for off in range(n_blocks * period):
-                new_layers[prefix + off] = jax.tree.map(
-                    lambda a: a[off], flat)
+            with jax.named_scope("kv_ring"):
+                flat = jax.tree.map(lambda a: a.reshape(
+                    (n_blocks * period,) + a.shape[2:]), ys)
+                for off in range(n_blocks * period):
+                    new_layers[prefix + off] = jax.tree.map(
+                        lambda a: a[off], flat)
     return x, new_layers
 
 
+@jax.named_scope("kv_ring")
 def _merge_cache(cfg: ModelConfig, cache: dict, new_layers, new_pos,
                  slot_pos=None) -> dict:
     out = dict(cache)
@@ -822,6 +845,7 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict,
 # slot-addressable serving (continuous batching)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_ring")
 def _tag_slots(slot_pos: jax.Array, positions: jax.Array) -> jax.Array:
     """Tag each row's ring slots with this step's absolute positions.
     slot_pos: (B,C), positions: (B,T) -> updated (B,C).  One batched

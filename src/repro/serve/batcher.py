@@ -35,6 +35,15 @@ The decode hot loop is one donated ``engine.step`` per tick regardless of
 how requests come and go, so throughput tracks slot occupancy instead of
 the lock-step batch's worst case.  Greedy outputs are bit-identical to
 running each request alone (`tests/test_serve_batcher.py`).
+
+Every tick leaves a ``TickRecord`` in ``last_tick``: its start and end on
+the batcher's clock, the seconds it blocked reading results back from the
+device, and the prefill and decode work it did.
+Under ``jax.profiler`` each tick is a ``serve.tick`` step span whose
+children name the phases that did work: ``serve.refill``,
+``serve.admit``, ``serve.prefill`` (chunk upload and dispatch),
+``serve.decode`` (token upload and step dispatch), ``serve.sync`` (a
+blocking readback) and ``serve.finish`` (bookkeeping after a readback).
 """
 
 from __future__ import annotations
@@ -47,6 +56,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.serve.engine import ServeEngine
 
@@ -93,6 +103,21 @@ class Completion:
         if self.admitted_s < 0:
             return 0.0
         return max(self.admitted_s - self.arrival_s, 0.0)
+
+
+@dataclasses.dataclass
+class TickRecord:
+    """One scheduler tick, on the batcher's clock (seconds from ``t0``)."""
+    start_s: float = 0.0
+    end_s: float = 0.0
+    readback_s: float = 0.0       # blocked reading tokens back
+    prefill_tokens: int = 0       # prompt tokens of the prefill chunk
+    decoded: int = 0              # slots the decode step advanced
+
+    @property
+    def host_s(self) -> float:
+        """The tick's length less its readback waits."""
+        return self.end_s - self.start_s - self.readback_s
 
 
 @dataclasses.dataclass
@@ -189,10 +214,7 @@ class ContinuousBatcher:
         self.prefix_tokens_saved = 0
         self._ring_bytes = 0
         self._admitted: dict[int, float] = {}   # rid -> admission time
-        # per-tick work counters, read by the fleet's virtual clock to
-        # price the tick (reset at the top of every tick)
-        self.tick_prefill_tokens = 0
-        self.tick_decoded = False
+        self.last_tick = TickRecord()
         self.t0 = self.clock()
 
     # -- scheduling --------------------------------------------------------
@@ -203,12 +225,32 @@ class ContinuousBatcher:
         self.queue.append(req)
 
     @property
+    def tick_prefill_tokens(self) -> int:
+        """Prompt tokens the last tick prefilled (the fleet's virtual clock
+        prices the tick from this and ``tick_decoded``)."""
+        return self.last_tick.prefill_tokens
+
+    @property
+    def tick_decoded(self) -> bool:
+        """Whether the last tick ran a decode step."""
+        return self.last_tick.decoded > 0
+
+    @property
     def busy(self) -> bool:
         return bool(self.queue or self.prefilling or self.active
                     or self.spilled)
 
     def _now(self) -> float:
         return self.clock() - self.t0
+
+    def _read_back(self, read: Callable):
+        """``read()``, which waits for the device's tokens: a
+        ``serve.sync`` span, its wait added to the tick's ``readback_s``."""
+        with TraceAnnotation("serve.sync"):
+            t = self.clock()
+            out = read()
+            self.last_tick.readback_s += self.clock() - t
+        return out
 
     def _finish(self, req: Request, new_tokens: list,
                 first_s: float = -1.0) -> None:
@@ -230,9 +272,10 @@ class ContinuousBatcher:
 
     def _admit(self) -> None:
         while self.queue and self.queue[0].max_new_tokens <= 0:
-            req = self.queue.popleft()
-            self._admitted[req.rid] = self._now()
-            self._finish(req, [])
+            with TraceAnnotation("serve.finish"):
+                req = self.queue.popleft()
+                self._admitted[req.rid] = self._now()
+                self._finish(req, [])
         if self.prefilling is not None or not self.queue:
             return
         inflight = len(self.active) + len(self.spilled)
@@ -242,33 +285,37 @@ class ContinuousBatcher:
             slot = -1       # prefill ahead; the finished ring parks spilled
         else:
             return
-        req = self.queue.popleft()
-        self._admitted[req.rid] = self._now()
-        cache, off = self.engine.new_request_cache(), 0
-        if self.prefix is not None:
-            hit = self.prefix.lookup(
-                req.prompt, max_len=min(len(req.prompt) - 1,
-                                        self.engine.scfg.cache_capacity))
-            if hit is not None:
-                off, state = hit
-                # restore = one refill off the backing tier; jnp.array in
-                # refill/prefill copies, so the stored state stays intact
-                cache = jax.tree.map(lambda a: jnp.array(a), state)
-                self._charge("refill")
-                self.prefix_hits += 1
-                self.prefix_tokens_saved += off
-        self.prefilling = _Prefill(req=req, cache=cache, off=off, slot=slot)
+        with TraceAnnotation("serve.admit"):
+            req = self.queue.popleft()
+            self._admitted[req.rid] = self._now()
+            cache, off = self.engine.new_request_cache(), 0
+            if self.prefix is not None:
+                hit = self.prefix.lookup(
+                    req.prompt, max_len=min(len(req.prompt) - 1,
+                                            self.engine.scfg.cache_capacity))
+                if hit is not None:
+                    off, state = hit
+                    # restore = one refill off the backing tier; jnp.array
+                    # in refill/prefill copies, so the stored state stays
+                    # intact
+                    cache = jax.tree.map(lambda a: jnp.array(a), state)
+                    self._charge("refill")
+                    self.prefix_hits += 1
+                    self.prefix_tokens_saved += off
+            self.prefilling = _Prefill(req=req, cache=cache, off=off,
+                                       slot=slot)
 
     def _prefill_tick(self) -> None:
         ps = self.prefilling
         if ps is None:
             return
         t = _chunk_len(len(ps.req.prompt) - ps.off, self.chunk_budget)
-        chunk = jnp.asarray(
-            ps.req.prompt[None, ps.off:ps.off + t], jnp.int32)
-        tok, ps.cache = self.engine.prefill_chunk(ps.cache, chunk)
+        with TraceAnnotation("serve.prefill"):
+            chunk = jnp.asarray(
+                ps.req.prompt[None, ps.off:ps.off + t], jnp.int32)
+            tok, ps.cache = self.engine.prefill_chunk(ps.cache, chunk)
         ps.off += t
-        self.tick_prefill_tokens = t
+        self.last_tick.prefill_tokens = t
         if ps.off < len(ps.req.prompt):
             # snapshot at the chunk boundary: a strict in-capacity prefix
             # whose ring has never wrapped — the prefix store's unit of
@@ -279,7 +326,14 @@ class ContinuousBatcher:
                                 jax.tree.map(lambda a: np.array(a),
                                              ps.cache))
             return
-        first = int(tok[0])
+        first = self._read_back(lambda: int(tok[0]))
+        with TraceAnnotation("serve.finish"):
+            self._place(ps, first)
+        self.prefilling = None
+
+    def _place(self, ps: _Prefill, first: int) -> None:
+        """A finished prefill, with its first token: done, handed off,
+        into its reserved slot, or parked on the backing tier."""
         now = self._now()
         if ps.req.max_new_tokens == 1:      # no decode needed
             self._finish(ps.req, [first], first_s=now)
@@ -308,7 +362,6 @@ class ContinuousBatcher:
                 req=ps.req, generated=[first], pending=first, state=state,
                 first_s=now, spilled_at=self.ticks, last_step=self.ticks)
             self._charge("spill")
-        self.prefilling = None
 
     def _lru_waiter(self) -> int:
         """rid of the spilled request to refill next: least-recently
@@ -348,42 +401,49 @@ class ContinuousBatcher:
         """Refill-ahead: spilled requests take freed slots before any new
         admission — a refill resumes decode this very tick, while a fresh
         admission still has its whole prefill in front of it."""
-        self._maybe_swap()
-        while self.free and self.spilled:
-            sp = self.spilled.pop(self._lru_waiter())
-            slot = self.free.pop()
-            self.engine.refill_slot(slot, sp.state)
-            self._charge("refill")
-            self.active[slot] = _Active(
-                req=sp.req, generated=sp.generated, first_s=sp.first_s,
-                last_step=self.ticks, since=self.ticks)
-            self.tokens[slot] = sp.pending
+        if not self.spilled:
+            return
+        with TraceAnnotation("serve.refill"):
+            self._maybe_swap()
+            while self.free and self.spilled:
+                sp = self.spilled.pop(self._lru_waiter())
+                slot = self.free.pop()
+                self.engine.refill_slot(slot, sp.state)
+                self._charge("refill")
+                self.active[slot] = _Active(
+                    req=sp.req, generated=sp.generated, first_s=sp.first_s,
+                    last_step=self.ticks, since=self.ticks)
+                self.tokens[slot] = sp.pending
 
     def _decode_tick(self) -> None:
         if not self.active:
             return
-        self.tick_decoded = True
-        nxt = np.asarray(self.engine.step(jnp.asarray(self.tokens)))
-        self.tokens = nxt.copy()
-        for slot in sorted(self.active):
-            st = self.active[slot]
-            st.last_step = self.ticks
-            st.generated.append(int(nxt[slot]))
-            if len(st.generated) >= st.req.max_new_tokens:
-                self._finish(st.req, st.generated, first_s=st.first_s)
-                self.engine.evict_slot(slot)
-                del self.active[slot]
-                self.free.append(slot)
+        self.last_tick.decoded = len(self.active)
+        with TraceAnnotation("serve.decode"):
+            out = self.engine.step(jnp.asarray(self.tokens))
+        nxt = self._read_back(lambda: np.asarray(out))
+        with TraceAnnotation("serve.finish"):
+            self.tokens = nxt.copy()
+            for slot in sorted(self.active):
+                st = self.active[slot]
+                st.last_step = self.ticks
+                st.generated.append(int(nxt[slot]))
+                if len(st.generated) >= st.req.max_new_tokens:
+                    self._finish(st.req, st.generated, first_s=st.first_s)
+                    self.engine.evict_slot(slot)
+                    del self.active[slot]
+                    self.free.append(slot)
 
     def tick(self) -> None:
         """One scheduler step: refill spilled work into freed slots, admit,
-        advance one prefill chunk, decode."""
-        self.tick_prefill_tokens = 0
-        self.tick_decoded = False
-        self._refill_tick()
-        self._admit()
-        self._prefill_tick()
-        self._decode_tick()
+        advance one prefill chunk, decode.  Leaves its ``last_tick``."""
+        rec = self.last_tick = TickRecord(start_s=self._now())
+        with StepTraceAnnotation("serve.tick", step_num=self.ticks):
+            self._refill_tick()
+            self._admit()
+            self._prefill_tick()
+            self._decode_tick()
+        rec.end_s = self._now()
         self.ticks += 1
 
     def adopt(self, req: Request, state: dict, generated: list,

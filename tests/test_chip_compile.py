@@ -11,6 +11,7 @@ CPU, so the tests steer kernel dispatch to the compiled kernel themselves.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +91,14 @@ def _cache(cfg, mesh, batch):
     return _shapes(shapes, cache_shardings(shapes, mesh))
 
 
+def _assert_scoped(hlo: str) -> None:
+    """The chip's compiler keeps the model's named scopes in the ops'
+    metadata, where a profiler trace reads them (``tf_op``)."""
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in ("layer", "attn", "mlp", "kv_ring", "head"):
+        assert any(f"/{scope}/" in n for n in names), scope
+
+
 @pytest.mark.parametrize("m", [SLOTS, 128])     # decode slots, prefill chunk
 def test_fused_mlp_fits_vmem_at_qwen3_widths(topo, m):
     one = SingleDeviceSharding(topo.devices[0])
@@ -126,6 +135,7 @@ def test_slot_decode_step_calls_kernel(topo, on_tpu):
                    donate_argnums=(2,))
     compiled = step.lower(params, tok, _cache(cfg, mesh, SLOTS)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_scoped(compiled.as_text())
 
 
 def test_chunk_prefill_step_calls_kernel(topo, on_tpu):
@@ -136,3 +146,4 @@ def test_chunk_prefill_step_calls_kernel(topo, on_tpu):
                     donate_argnums=(2,))
     compiled = chunk.lower(params, toks, _cache(cfg, mesh, 1)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _assert_scoped(compiled.as_text())
