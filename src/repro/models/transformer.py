@@ -16,17 +16,20 @@ where ``period`` is the MoE interleave (llama4: dense/MoE alternation => 2)
 stays O(period), not O(num_layers).  Leading non-periodic layers
 (kimi's dense first layer) run unrolled.
 
-Caches are stacked over layers (leading dim ``num_layers``) and threaded
-through the scan as xs/ys slices.  KV caches are ring buffers of capacity
+Caches are stacked over layers (leading dim ``num_layers``) and ride
+through the layer loop in its carry: layer ``li`` reads its slice where it
+lies and writes back only what it changed — each row's new K/V, or a whole
+layer's state — in place, so a step never copies the stacked cache.  KV
+caches are ring buffers of capacity
 ``C``: exact attention while ``pos < C`` and sliding-window semantics
 beyond — full-attention serving sizes ``C = seq_len``, SWA archs size
 ``C = window`` (how hymba/danube hold ``long_500k`` state in O(window)).
 
 Every op carries a named scope in its metadata, so a profiler trace can
 split a step by them: ``layer`` (one decoder layer), ``attn``, ``mlp``,
-``head``, and ``kv_ring`` for every move of the cache (the ring stacked
-into and out of the layer scan, the per-layer slice, the merge, the
-position tags and the write of new K/V).
+``head``, and ``kv_ring`` for every read and write of the cache (a
+layer's slice, the in-place writes of new K/V and state, and the position
+tags).
 """
 
 from __future__ import annotations
@@ -312,85 +315,121 @@ def _self_attention_full(x, p, cfg: ModelConfig, spec: AttnSpec,
     return linear(out, p["w_o"]), k, v
 
 
+@jax.named_scope("kv_ring")
+def _at(buf: jax.Array, li) -> jax.Array:
+    """Layer ``li``'s slice of a cache leaf stacked over layers."""
+    return lax.dynamic_index_in_dim(buf, li, 0, keepdims=False)
+
+
+@jax.named_scope("kv_ring")
+def _put(buf: jax.Array, val: jax.Array, li) -> jax.Array:
+    """Replace layer ``li`` of a stacked cache leaf, in place."""
+    return lax.dynamic_update_index_in_dim(buf, val.astype(buf.dtype), li, 0)
+
+
+@jax.named_scope("kv_ring")
+def _put_slot(buf: jax.Array, val: jax.Array, li, slot) -> jax.Array:
+    """Write val (B,H,1,*) at ring slot ``slot`` of layer ``li``, every
+    row, in place."""
+    return lax.dynamic_update_slice(buf, val[None].astype(buf.dtype),
+                                    (li, 0, 0, slot, 0))
+
+
+@jax.named_scope("kv_ring")
+def _put_rows(buf: jax.Array, val: jax.Array, li,
+              positions: jax.Array) -> jax.Array:
+    """Write new rows val (B,H,T,*) into layer ``li`` of a stacked ring
+    (L,B,H,C,*): row r's T tokens sit at the consecutive positions
+    ``positions[r]`` (T <= C), each at its position modulo C.  In-place
+    updates only, one run per row (B is static): a scatter here makes the
+    compiler copy the whole stacked ring around the layer loop.  A run
+    that wraps past slot C-1 is written as two windows, [C-T, C) and
+    [0, T), each keeping the old rows it does not own."""
+    b, h, t, e = val.shape
+    c = buf.shape[3]
+    val = val.astype(buf.dtype)
+
+    def write(buf, run, r, start):
+        return lax.dynamic_update_slice(buf, run[None, None],
+                                        (li, r, 0, start, 0))
+
+    for r in range(b):
+        first = positions[r, 0] % c
+        if t == 1:
+            buf = write(buf, val[r], r, first)
+            continue
+        start = jnp.minimum(first, c - t)   # the window stays inside C
+        wrap = first - start                # the run's last `wrap` tokens
+        run = jnp.roll(val[r], wrap, axis=1)    # land in slots [0, wrap)
+        idx = jnp.arange(t)[None, :, None]
+        for lo, mine in ((start, idx >= wrap), (0, idx < wrap)):
+            old = lax.dynamic_slice(buf, (li, r, 0, lo, 0),
+                                    (1, 1, h, t, e))[0, 0]
+            buf = write(buf, jnp.where(mine, run, old), r, lo)
+    return buf
+
+
+def _store_kv(state: dict, k_new, v_new, put) -> dict:
+    """Write one layer's new K/V (B,H,T,hd) into the stacked cache with
+    ``put(buf, val)``; an int8 cache stores them quantized, with scales."""
+    if "k_scale" in state:
+        (k_new, k_sc), (v_new, v_sc) = _quantize_kv(k_new), _quantize_kv(v_new)
+        new = {"k": k_new, "v": v_new, "k_scale": k_sc, "v_scale": v_sc}
+    else:
+        new = {"k": k_new, "v": v_new}
+    return {**state, **{key: put(state[key], val) for key, val in new.items()}}
+
+
+def _load_kv(state: dict, li) -> tuple[jax.Array, jax.Array]:
+    """Layer ``li``'s K/V rings (B,H,C,hd), dequantized if int8."""
+    k, v = _at(state["k"], li), _at(state["v"], li)
+    if "k_scale" in state:
+        k = _dequantize_kv(k, _at(state["k_scale"], li))
+        v = _dequantize_kv(v, _at(state["v_scale"], li))
+    return k, v
+
+
 @jax.named_scope("attn")
 def _self_attention_decode(x, p, cfg: ModelConfig, spec: AttnSpec,
-                           k_cache, v_cache, kq_scales, slot_pos, pos):
-    """x: (B,1,d) one token at absolute position ``pos`` against the ring
-    cache (B,Hkv,C,hd).  Returns (out, new_k_slice, new_v_slice)."""
+                           state: dict, li, slot_pos, pos):
+    """x: (B,1,d) one token at absolute position ``pos`` against layer
+    ``li`` of the stacked ring cache (L,B,Hkv,C,hd).  Returns (out, state)
+    with the token's K/V written at ring slot ``pos % C``."""
     b, s, d = x.shape
-    c = k_cache.shape[2]
+    c = state["k"].shape[3]
     q, k_new, v_new = _project_qkv(x, p, cfg)         # (B,H,1,hd)
     sin, cos = rope_tables(jnp.full((1,), pos), spec.head_dim, cfg.rope_theta)
     q = apply_rope(q, sin, cos)
     k_new = apply_rope(k_new, sin, cos)
-    slot = pos % c
-    if kq_scales is not None:
-        kq, ks = _quantize_kv(k_new)
-        vq, vs = _quantize_kv(v_new)
-        with jax.named_scope("kv_ring"):
-            k_cache = lax.dynamic_update_slice_in_dim(k_cache, kq, slot, 2)
-            v_cache = lax.dynamic_update_slice_in_dim(v_cache, vq, slot, 2)
-            k_sc = lax.dynamic_update_slice_in_dim(kq_scales[0], ks, slot, 2)
-            v_sc = lax.dynamic_update_slice_in_dim(kq_scales[1], vs, slot, 2)
-        k = _dequantize_kv(k_cache, k_sc)
-        v = _dequantize_kv(v_cache, v_sc)
-        new_scales = (k_sc, v_sc)
-    else:
-        with jax.named_scope("kv_ring"):
-            k_cache = lax.dynamic_update_slice_in_dim(
-                k_cache, k_new.astype(k_cache.dtype), slot, axis=2)
-            v_cache = lax.dynamic_update_slice_in_dim(
-                v_cache, v_new.astype(v_cache.dtype), slot, axis=2)
-        k, v = k_cache, v_cache
-        new_scales = None
+    state = _store_kv(state, k_new, v_new,
+                      lambda buf, val: _put_slot(buf, val, li, pos % c))
+    k, v = _load_kv(state, li)
     bias = attn_mask_bias(spec, jnp.full((1,), pos), slot_pos)
     out = gqa_attention(q, k.astype(q.dtype), v.astype(q.dtype), bias, spec)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, -1)
-    return linear(out, p["w_o"]), k_cache, v_cache, new_scales
+    return linear(out, p["w_o"]), state
 
 
 @jax.named_scope("attn")
 def _self_attention_slots(x, p, cfg: ModelConfig, spec: AttnSpec,
-                          k_cache, v_cache, kq_scales, slot_pos, positions):
+                          state: dict, li, slot_pos, positions):
     """Per-slot cached attention: every batch row sits at its own absolute
     position.  x: (B,T,d), positions: (B,T) per-row token positions,
     slot_pos: (B,C) per-row ring tags (already updated for this step's
-    writes), caches (B,Hkv,C,hd).  Serves both the continuous-batching
-    decode step (T=1, B=slots) and chunked prefill (B=1, T=chunk)."""
+    writes), ``state``'s rings (L,B,Hkv,C,hd), read and written at layer
+    ``li``.  Serves both the continuous-batching decode step (T=1,
+    B=slots) and chunked prefill (B=1, T=chunk).  Returns (out, state)."""
     b, t, d = x.shape
-    c = k_cache.shape[2]
     q, k_new, v_new = _project_qkv(x, p, cfg)          # (B,H,T,hd)
     sin, cos = rope_tables(positions, spec.head_dim, cfg.rope_theta)
     q = apply_rope(q, sin, cos)
     k_new = apply_rope(k_new, sin, cos)
 
-    # scatter the T new K/V rows into each row's ring slots: touches only
-    # the T written slots (T <= C keeps them distinct), so with donated
+    # only the T written slots of each row are touched, so with donated
     # buffers the per-token write is O(T), not O(C)
-    rows = jnp.arange(b)[:, None]                      # (B,1)
-    slots = positions % c                              # (B,T)
-
-    @jax.named_scope("kv_ring")
-    def scatter(buf, val):                             # val: (B,H,T,*)
-        return buf.at[rows, :, slots, :].set(
-            val.astype(buf.dtype).transpose(0, 2, 1, 3),
-            unique_indices=True)
-
-    if kq_scales is not None:
-        kq, ks = _quantize_kv(k_new)
-        vq, vs = _quantize_kv(v_new)
-        k_cache = scatter(k_cache, kq)
-        v_cache = scatter(v_cache, vq)
-        k_sc = scatter(kq_scales[0], ks)
-        v_sc = scatter(kq_scales[1], vs)
-        k = _dequantize_kv(k_cache, k_sc)
-        v = _dequantize_kv(v_cache, v_sc)
-        new_scales = (k_sc, v_sc)
-    else:
-        k_cache = scatter(k_cache, k_new)
-        v_cache = scatter(v_cache, v_new)
-        k, v = k_cache, v_cache
-        new_scales = None
+    state = _store_kv(state, k_new, v_new,
+                      lambda buf, val: _put_rows(buf, val, li, positions))
+    k, v = _load_kv(state, li)
 
     # per-row additive mask from the ring tags (sentinel slots mask out)
     ok = slot_pos[:, None, :] <= positions[:, :, None]
@@ -400,7 +439,7 @@ def _self_attention_slots(x, p, cfg: ModelConfig, spec: AttnSpec,
     bias = bias[:, None, None, :, :]                   # (B,1,1,T,C)
     out = gqa_attention(q, k.astype(q.dtype), v.astype(q.dtype), bias, spec)
     out = out.transpose(0, 2, 1, 3).reshape(b, t, -1)
-    return linear(out, p["w_o"]), k_cache, v_cache, new_scales
+    return linear(out, p["w_o"]), state
 
 
 @jax.named_scope("attn")
@@ -439,59 +478,41 @@ def _ffn(x, p, cfg: ModelConfig, kind: str, mode: str = "train",
 
 @jax.named_scope("layer")
 def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,
-                   ctx: dict, layer_cache: Optional[dict]):
-    """Apply one decoder layer.  Returns (x, updated layer cache slices)."""
-    new_cache: dict = {}
+                   ctx: dict, state: Optional[dict], li):
+    """Apply decoder layer ``li``.  ``state`` holds the cache leaves stacked
+    over layers (None without a cache); the layer writes what it changed
+    into them in place.  Returns (x, state)."""
     if kind == "rwkv":
-        state = layer_cache.get("rwkv_state") if layer_cache else None
-        x, new_state = rwkv_mod.rwkv_block(x, p, cfg, state)
-        if layer_cache is not None:
-            new_cache["rwkv_state"] = new_state
-        return x, new_cache
+        prev = None if state is None else _at(state["rwkv_state"], li)
+        x, new = rwkv_mod.rwkv_block(x, p, cfg, prev)
+        if state is not None:
+            state = {**state, "rwkv_state": _put(state["rwkv_state"], new, li)}
+        return x, state
 
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if ctx["mode"] == "decode":
-        attn_out, k_c, v_c, scales = _self_attention_decode(
-            h, p["attn"], cfg, spec, layer_cache["k"], layer_cache["v"],
-            layer_cache.get("scales"), ctx["slot_pos"], ctx["pos"])
-        new_cache.update(k=k_c, v=v_c)
-        if scales is not None:
-            new_cache["scales"] = scales
+        attn_out, state = _self_attention_decode(
+            h, p["attn"], cfg, spec, state, li, ctx["slot_pos"], ctx["pos"])
     elif ctx["mode"] == "slots":
-        attn_out, k_c, v_c, scales = _self_attention_slots(
-            h, p["attn"], cfg, spec, layer_cache["k"], layer_cache["v"],
-            layer_cache.get("scales"), ctx["slot_pos"], ctx["positions"])
-        new_cache.update(k=k_c, v=v_c)
-        if scales is not None:
-            new_cache["scales"] = scales
+        attn_out, state = _self_attention_slots(
+            h, p["attn"], cfg, spec, state, li, ctx["slot_pos"],
+            ctx["positions"])
     else:
         attn_out, k, v = _self_attention_full(
             h, p["attn"], cfg, spec, ctx["sin"], ctx["cos"],
             ctx["positions"], ctx.get("mesh"), ctx.get("layout", "tp"))
-        if layer_cache is not None:   # prefill: write the cache
-            c = layer_cache["k"].shape[2]
-            s = k.shape[2]
-            kw = k[:, :, -c:, :]
-            vw = v[:, :, -c:, :]
-            if layer_cache["k"].dtype == jnp.int8:
-                kq, ks = _quantize_kv(kw)
-                vq, vs = _quantize_kv(vw)
-                new_cache.update(
-                    k=_fill_ring(layer_cache["k"], kq, s),
-                    v=_fill_ring(layer_cache["v"], vq, s),
-                    scales=(_fill_ring(layer_cache["scales"][0], ks, s),
-                            _fill_ring(layer_cache["scales"][1], vs, s)))
-            else:
-                new_cache.update(
-                    k=_fill_ring(layer_cache["k"], kw, s),
-                    v=_fill_ring(layer_cache["v"], vw, s))
+        if state is not None:   # prefill: write the layer's whole ring
+            c, s = state["k"].shape[3], k.shape[2]
+            state = _store_kv(
+                state, k[:, :, -c:, :], v[:, :, -c:, :],
+                lambda buf, val: _put(buf, _fill_ring(val, c, s), li))
 
     if cfg.hybrid_parallel_ssm:
-        state = layer_cache.get("ssm_state") if layer_cache else None
-        ssm_out, new_state = ssm_mod.ssm_branch(h, p["ssm"], cfg, state)
+        prev = None if state is None else _at(state["ssm_state"], li)
+        ssm_out, new = ssm_mod.ssm_branch(h, p["ssm"], cfg, prev)
         attn_out = attn_out + ssm_out
-        if layer_cache is not None:
-            new_cache["ssm_state"] = new_state
+        if state is not None:
+            state = {**state, "ssm_state": _put(state["ssm_state"], new, li)}
     x = x + attn_out
 
     if cfg.encoder_layers:
@@ -502,22 +523,21 @@ def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,
 
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     x = x + _ffn(h2, p, cfg, kind, ctx["mode"], ctx.get("mesh"))
-    return x, new_cache
+    return x, state
 
 
 @jax.named_scope("kv_ring")
-def _fill_ring(buf: jax.Array, val: jax.Array, total_seq: int) -> jax.Array:
-    """Write a prefill's last-C tokens into the ring with the true ring
-    layout: position ``p`` lands at slot ``p % C`` (so later decode steps
-    evict exactly the token leaving the window)."""
-    c = buf.shape[2]
+def _fill_ring(val: jax.Array, c: int, total_seq: int) -> jax.Array:
+    """Lay a prefill's last-C tokens val (B,H,min(S,C),*) out as a ring of
+    capacity ``c``: position ``p`` lands at slot ``p % C`` (so later decode
+    steps evict exactly the token leaving the window)."""
     s = val.shape[2]           # = min(total_seq, c)
     if s < c:
         val = jnp.pad(val, ((0, 0), (0, 0), (0, c - s), (0, 0)))
     shift = (total_seq - s) % c
     if shift:
         val = jnp.roll(val, shift, axis=2)
-    return val.astype(buf.dtype)
+    return val
 
 
 # ---------------------------------------------------------------------------
@@ -589,119 +609,64 @@ def _seq_shard_hidden(x, mesh):
         x, NamedSharding(mesh, P(b_ax, "model", None)))
 
 
+_LAYER_KEYS = ("k", "v", "k_scale", "v_scale", "rwkv_state", "ssm_state")
+
+
+def _layer_state(cache: Optional[dict]) -> Optional[dict]:
+    """The cache leaves stacked over layers, which the layer loop carries."""
+    if cache is None:
+        return None
+    return {key: cache[key] for key in _LAYER_KEYS if key in cache}
+
+
 def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
-    """Apply prefix layers then the scanned blocks; threads per-layer cache
-    slices in/out.  Returns (x, new_cache_layers: list aligned to layers)."""
+    """Apply prefix layers then the scanned blocks.  The cache's stacked
+    leaves ride in the loop's carry: layer ``prefix + block * period +
+    slot`` reads its slice at that index and writes its new K/V rows (or
+    its whole state) there in place, so the ring is neither sliced into
+    the scan's xs nor restacked from its ys.  Returns (x, the updated
+    stacked leaves, or None without a cache)."""
     prefix, period, n_blocks = block_structure(cfg)
     kinds = layer_kinds(cfg)
     spec = attn_spec(cfg)
-    new_layers: list[Optional[dict]] = [None] * cfg.num_layers
-
-    @jax.named_scope("kv_ring")
-    def cache_slice(li):
-        if cache is None:
-            return None
-        out = {}
-        for key in ("k", "v", "rwkv_state", "ssm_state"):
-            if key in cache:
-                out[key] = cache[key][li]
-        if "k_scale" in cache:
-            out["scales"] = (cache["k_scale"][li], cache["v_scale"][li])
-        return out
+    state = _layer_state(cache)
 
     for li in range(prefix):
-        lc = cache_slice(li)
-        x, nc = _decoder_layer(x, params["prefix"][li], cfg, kinds[li],
-                               spec, ctx, lc)
-        new_layers[li] = nc
+        x, state = _decoder_layer(x, params["prefix"][li], cfg, kinds[li],
+                                  spec, ctx, state, li)
+    if not n_blocks:
+        return x, state
 
-    if n_blocks:
-        xs_cache = None
-        if cache is not None:
-            @jax.named_scope("kv_ring")
-            def stack_blocks(arr):
-                L = arr.shape[0]
-                body = arr[prefix:prefix + n_blocks * period]
-                return body.reshape((n_blocks, period) + arr.shape[1:])
-            xs_cache = {}
-            for key in ("k", "v", "rwkv_state", "ssm_state"):
-                if key in cache:
-                    xs_cache[key] = stack_blocks(cache[key])
-            if "k_scale" in cache:
-                xs_cache["scales"] = (stack_blocks(cache["k_scale"]),
-                                      stack_blocks(cache["v_scale"]))
+    def block_body(carry, xs):
+        x, state = carry
+        bparams, bi = xs
+        for slot in range(period):
+            x, state = _decoder_layer(x, bparams[slot], cfg,
+                                      kinds[prefix + slot], spec, ctx, state,
+                                      prefix + bi * period + slot)
+        x = _seq_shard_hidden(x, ctx.get("mesh"))
+        return (x, state), None
 
-        def block_body(x, xs):
-            bparams, bcache = xs
-            outs = []
-            for slot in range(period):
-                kind = kinds[prefix + slot]
-                with jax.named_scope("kv_ring"):
-                    lc = (jax.tree.map(lambda a: a[slot], bcache)
-                          if bcache is not None else None)
-                x, nc = _decoder_layer(x, bparams[slot], cfg, kind,
-                                       spec, ctx, lc)
-                outs.append(nc)
-            with jax.named_scope("kv_ring"):
-                ys = (jax.tree.map(lambda *zs: jnp.stack(zs), *outs)
-                      if outs[0] else None)
-            x = _seq_shard_hidden(x, ctx.get("mesh"))
-            return x, ys
-
-        if remat:
-            block_body = jax.checkpoint(block_body)
-        bparams = tuple(params["blocks"])
-        if cfg.unroll_scan:
-            # Python loop: accounting variants (cost_analysis counts every
-            # unrolled body; a while/scan body is counted once)
-            ys_list = []
-            for bi in range(n_blocks):
-                xs_i = jax.tree.map(lambda a: a[bi], (bparams, xs_cache))
-                x, ys_i = block_body(x, xs_i)
-                ys_list.append(ys_i)
-            ys = (jax.tree.map(lambda *zs: jnp.stack(zs), *ys_list)
-                  if ys_list and ys_list[0] is not None else None)
-        else:
-            x, ys = lax.scan(block_body, x, (bparams, xs_cache))
-        if ys is not None:
-            # unstack ys back into per-layer entries
-            with jax.named_scope("kv_ring"):
-                flat = jax.tree.map(lambda a: a.reshape(
-                    (n_blocks * period,) + a.shape[2:]), ys)
-                for off in range(n_blocks * period):
-                    new_layers[prefix + off] = jax.tree.map(
-                        lambda a: a[off], flat)
-    return x, new_layers
+    if remat:
+        block_body = jax.checkpoint(block_body)
+    bparams = tuple(params["blocks"])
+    if cfg.unroll_scan:
+        # Python loop: accounting variants (cost_analysis counts every
+        # unrolled body; a while/scan body is counted once)
+        for bi in range(n_blocks):
+            (x, state), _ = block_body(
+                (x, state), (jax.tree.map(lambda a: a[bi], bparams), bi))
+    else:
+        (x, state), _ = lax.scan(
+            block_body, (x, state),
+            (bparams, jnp.arange(n_blocks, dtype=jnp.int32)))
+    return x, state
 
 
-@jax.named_scope("kv_ring")
-def _merge_cache(cfg: ModelConfig, cache: dict, new_layers, new_pos,
+def _merge_cache(cache: dict, state: Optional[dict], new_pos,
                  slot_pos=None) -> dict:
-    out = dict(cache)
-    if new_layers[0] is None and all(nl is None for nl in new_layers):
-        out["pos"] = new_pos
-        return out
-
-    def gather(key, sub=None):
-        vals = []
-        for nl in new_layers:
-            v = nl[key]
-            if sub is not None:
-                v = v[sub]
-            vals.append(v)
-        return jnp.stack(vals)
-
-    any_layer = new_layers[0]
-    if "k" in any_layer:
-        out["k"] = gather("k")
-        out["v"] = gather("v")
-        if "scales" in any_layer:
-            out["k_scale"] = gather("scales", 0)
-            out["v_scale"] = gather("scales", 1)
-    for key in ("rwkv_state", "ssm_state"):
-        if key in any_layer:
-            out[key] = gather(key)
-    out["pos"] = new_pos
+    """The step's cache: the layer loop's updated leaves and new tags."""
+    out = {**cache, **(state or {}), "pos": new_pos}
     if slot_pos is not None:
         out["slot_pos"] = slot_pos
     return out
@@ -739,10 +704,11 @@ def _run_decoder_with_cross(params, cfg, x, ctx, cache, remat=False):
     # per-layer cross K/V computed from enc_out or read from the cache.
     kinds = layer_kinds(cfg)
     spec = attn_spec(cfg)
-    new_layers = [None] * cfg.num_layers
+    state = _layer_state(cache)
     hd, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
     all_params = ([params["prefix"][i] for i in range(len(params["prefix"]))]
                   + _unstack_blocks(params, cfg))
+    cross = []
     for li in range(cfg.num_layers):
         p = all_params[li]
         if "enc_out" in ctx and ctx["enc_out"] is not None:
@@ -752,33 +718,30 @@ def _run_decoder_with_cross(params, cfg, x, ctx, cache, remat=False):
                 b, es, nkv, hd).transpose(0, 2, 1, 3)
             cv = linear(eo, p["xattn"]["w_v"]).reshape(
                 b, es, nkv, hd).transpose(0, 2, 1, 3)
+            cross.append((ck, cv))
         else:
             ck = cache["cross_k"][li]
             cv = cache["cross_v"][li]
         lctx = dict(ctx)
         lctx["cross_k"], lctx["cross_v"] = ck, cv
-        lc = None
-        if cache is not None:
-            lc = {"k": cache["k"][li], "v": cache["v"][li]}
-            if "k_scale" in cache:
-                lc["scales"] = (cache["k_scale"][li], cache["v_scale"][li])
-        if remat and ctx["mode"] == "train" and lc is None:
+        if remat and ctx["mode"] == "train" and state is None:
             # remat per layer; only array leaves may cross the checkpoint
             stat = {k: v for k, v in lctx.items() if not hasattr(v, "ndim")}
             arrs = {k: v for k, v in lctx.items() if hasattr(v, "ndim")}
             kind_i = kinds[li]
 
-            def f(x, p, actx, _stat=stat, _kind=kind_i):
+            def f(x, p, actx, _stat=stat, _kind=kind_i, _li=li):
                 return _decoder_layer(x, p, cfg, _kind, spec,
-                                      {**_stat, **actx}, None)
+                                      {**_stat, **actx}, None, _li)
 
-            x, nc = jax.checkpoint(f)(x, p, arrs)
+            x, _ = jax.checkpoint(f)(x, p, arrs)
         else:
-            x, nc = _decoder_layer(x, p, cfg, kinds[li], spec, lctx, lc)
-        if cache is not None:
-            nc["cross_k"], nc["cross_v"] = ck, cv
-        new_layers[li] = nc
-    return x, new_layers
+            x, state = _decoder_layer(x, p, cfg, kinds[li], spec, lctx,
+                                      state, li)
+    if state is not None and cross:     # prefill: the cache keeps cross K/V
+        state["cross_k"] = jnp.stack([ck for ck, _ in cross])
+        state["cross_v"] = jnp.stack([cv for _, cv in cross])
+    return x, state
 
 
 def _unstack_blocks(params, cfg: ModelConfig) -> list:
@@ -803,7 +766,7 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, cache: dict,
     if cfg.encoder_layers:
         ctx["enc_out"] = _encode(params, cfg, enc_embeds)
         x = x + params["dec_pos"][None, :s, :]
-    x, new_layers = _run_decoder_with_cross(params, cfg, x, ctx, cache)
+    x, state = _run_decoder_with_cross(params, cfg, x, ctx, cache)
     slot_pos = None
     if "slot_pos" in cache:
         cap = cache["slot_pos"].shape[0]
@@ -813,10 +776,7 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, cache: dict,
         else:       # ring layout: slot j holds position p=start+((j-start)%C)
             start = s - cap
             slot_pos = start + (idx - start) % cap
-    new_cache = _merge_cache(cfg, cache, new_layers, jnp.int32(s), slot_pos)
-    if cfg.encoder_layers and new_layers[0] is not None:
-        new_cache["cross_k"] = jnp.stack([nl["cross_k"] for nl in new_layers])
-        new_cache["cross_v"] = jnp.stack([nl["cross_v"] for nl in new_layers])
+    new_cache = _merge_cache(cache, state, jnp.int32(s), slot_pos)
     return _logits(params, cfg, x[:, -1:, :]), new_cache
 
 
@@ -836,8 +796,8 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict,
         slot_pos = cache["slot_pos"].at[pos % c].set(pos)
     ctx = {"mode": "decode", "pos": pos, "slot_pos": slot_pos,
            "enc_out": None, "mesh": mesh}
-    x, new_layers = _run_decoder_with_cross(params, cfg, x, ctx, cache)
-    new_cache = _merge_cache(cfg, cache, new_layers, pos + 1, slot_pos)
+    x, state = _run_decoder_with_cross(params, cfg, x, ctx, cache)
+    new_cache = _merge_cache(cache, state, pos + 1, slot_pos)
     return _logits(params, cfg, x), new_cache
 
 
@@ -880,8 +840,8 @@ def decode_slots(params, cfg: ModelConfig, token: jax.Array, cache: dict,
     pos = cache["pos"]                                  # (B,)
     positions = pos[:, None]                            # (B,1)
     ctx, slot_pos = _slots_ctx(cache, positions, mesh)
-    x, new_layers = _run_decoder(params, cfg, x, ctx, cache)
-    new_cache = _merge_cache(cfg, cache, new_layers, pos + 1, slot_pos)
+    x, state = _run_decoder(params, cfg, x, ctx, cache)
+    new_cache = _merge_cache(cache, state, pos + 1, slot_pos)
     return _logits(params, cfg, x), new_cache
 
 
@@ -909,8 +869,8 @@ def chunk_prefill(params, cfg: ModelConfig, tokens: jax.Array, cache: dict,
         raise ValueError(f"chunk of {t} tokens exceeds cache capacity "
                          f"{cache['slot_pos'].shape[1]}")
     ctx, slot_pos = _slots_ctx(cache, positions, mesh)
-    x, new_layers = _run_decoder(params, cfg, x, ctx, cache)
-    new_cache = _merge_cache(cfg, cache, new_layers, pos + t, slot_pos)
+    x, state = _run_decoder(params, cfg, x, ctx, cache)
+    new_cache = _merge_cache(cache, state, pos + t, slot_pos)
     return _logits(params, cfg, x[:, -1:, :]), new_cache
 
 

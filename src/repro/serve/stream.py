@@ -18,8 +18,7 @@ block's compute because they have no data dependency.
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -64,19 +63,18 @@ def streaming_decoder(params, cfg: ModelConfig, x, ctx, cache,
     """Drop-in replacement for ``transformer._run_decoder`` that streams
     block weights with an ELK gather-ahead window of depth ``prefetch``.
 
-    Returns (x, new_cache_layers) with the same contract."""
+    Returns (x, the updated stacked cache leaves) with the same contract:
+    the cache rides in the carry and each layer writes it in place."""
     prefix, period, n_blocks = tfm.block_structure(cfg)
     kinds = tfm.layer_kinds(cfg)
     spec = tfm.attn_spec(cfg)
-    new_layers: list[Optional[dict]] = [None] * cfg.num_layers
+    state = tfm._layer_state(cache)
 
     for li in range(prefix):
-        lc = _cache_slice(cache, li)
-        x, nc = tfm._decoder_layer(x, params["prefix"][li], cfg, kinds[li],
-                                   spec, ctx, lc)
-        new_layers[li] = nc
+        x, state = tfm._decoder_layer(x, params["prefix"][li], cfg,
+                                      kinds[li], spec, ctx, state, li)
     if not n_blocks:
-        return x, new_layers
+        return x, state
 
     pblocks = tuple(params["blocks"])
     exec_sh = execute_state_shardings(pblocks, mesh)
@@ -87,66 +85,24 @@ def streaming_decoder(params, cfg: ModelConfig, x, ctx, cache,
     window = [_gather(pblocks, jnp.int32(i), exec_sh) for i in range(p)]
     window = jax.tree.map(lambda *xs: jnp.stack(xs), *window)
 
-    xs_cache = _stack_cache(cache, cfg) if cache is not None else None
-
-    def body(carry, step_xs):
-        x, win = carry
-        i, bcache = step_xs
+    def body(carry, i):
+        x, win, state = carry
         cur = jax.tree.map(lambda a: a[0], win)
-        outs = []
         for slot in range(period):
-            kind = kinds[prefix + slot]
-            lc = (jax.tree.map(lambda a: a[slot], bcache)
-                  if bcache is not None else None)
-            x, nc = tfm._decoder_layer(x, cur[slot], cfg, kind, spec,
-                                       ctx, lc)
-            outs.append(nc)
+            x, state = tfm._decoder_layer(x, cur[slot], cfg,
+                                          kinds[prefix + slot], spec, ctx,
+                                          state, prefix + i * period + slot)
         # issue the gather of block i+p (clamped; tail gathers are no-ops
         # on already-resident data)
         nxt = _gather(pblocks, jnp.minimum(i + p, n_blocks - 1), exec_sh)
         win = jax.tree.map(
             lambda a, n: jnp.concatenate([a[1:], n[None]], axis=0),
             win, nxt)
-        ys = (jax.tree.map(lambda *zs: jnp.stack(zs), *outs)
-              if outs[0] else None)
-        return (x, win), ys
+        return (x, win, state), None
 
     idxs = jnp.arange(n_blocks, dtype=jnp.int32)
-    (x, _), ys = lax.scan(body, (x, window), (idxs, xs_cache))
-    if ys is not None:
-        flat = jax.tree.map(
-            lambda a: a.reshape((n_blocks * period,) + a.shape[2:]), ys)
-        for off in range(n_blocks * period):
-            new_layers[prefix + off] = jax.tree.map(lambda a: a[off], flat)
-    return x, new_layers
-
-
-def _cache_slice(cache, li):
-    if cache is None:
-        return None
-    out = {}
-    for key in ("k", "v", "rwkv_state", "ssm_state"):
-        if key in cache:
-            out[key] = cache[key][li]
-    if "k_scale" in cache:
-        out["scales"] = (cache["k_scale"][li], cache["v_scale"][li])
-    return out
-
-
-def _stack_cache(cache, cfg: ModelConfig):
-    prefix, period, n_blocks = tfm.block_structure(cfg)
-
-    def stack(arr):
-        body = arr[prefix:prefix + n_blocks * period]
-        return body.reshape((n_blocks, period) + arr.shape[1:])
-
-    out = {}
-    for key in ("k", "v", "rwkv_state", "ssm_state"):
-        if key in cache:
-            out[key] = stack(cache[key])
-    if "k_scale" in cache:
-        out["scales"] = (stack(cache["k_scale"]), stack(cache["v_scale"]))
-    return out
+    (x, _, state), _ = lax.scan(body, (x, window, state), idxs)
+    return x, state
 
 
 def streaming_decode_slots(params, cfg: ModelConfig, token, cache,
@@ -162,9 +118,8 @@ def streaming_decode_slots(params, cfg: ModelConfig, token, cache,
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     pos = cache["pos"]                                  # (B,)
     ctx, slot_pos = tfm._slots_ctx(cache, pos[:, None], mesh)
-    x, new_layers = streaming_decoder(params, cfg, x, ctx, cache, mesh,
-                                      prefetch)
-    new_cache = tfm._merge_cache(cfg, cache, new_layers, pos + 1, slot_pos)
+    x, state = streaming_decoder(params, cfg, x, ctx, cache, mesh, prefetch)
+    new_cache = tfm._merge_cache(cache, state, pos + 1, slot_pos)
     return tfm._logits(params, cfg, x), new_cache
 
 
@@ -187,7 +142,6 @@ def streaming_decode_step(params, cfg: ModelConfig, token, cache,
         slot_pos = cache["slot_pos"].at[pos % c].set(pos)
     ctx = {"mode": "decode", "pos": pos, "slot_pos": slot_pos,
            "enc_out": None, "mesh": mesh}
-    x, new_layers = streaming_decoder(params, cfg, x, ctx, cache, mesh,
-                                      prefetch)
-    new_cache = tfm._merge_cache(cfg, cache, new_layers, pos + 1, slot_pos)
+    x, state = streaming_decoder(params, cfg, x, ctx, cache, mesh, prefetch)
+    new_cache = tfm._merge_cache(cache, state, pos + 1, slot_pos)
     return tfm._logits(params, cfg, x), new_cache

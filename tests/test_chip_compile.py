@@ -147,3 +147,55 @@ def test_chunk_prefill_step_calls_kernel(topo, on_tpu):
     compiled = chunk.lower(params, toks, _cache(cfg, mesh, 1)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _assert_scoped(compiled.as_text())
+
+
+# the benchmark's two configurations: (config, layers, slots, ring tokens)
+BENCH_STEPS = {
+    "h2o_danube_1_8b": (get_config("h2o_danube_1_8b"), 24, 8, 4224),
+    "qwen3_14b": (QWEN3, 12, 12, 4096),
+}
+
+
+def _ring_moves(hlo: str, shape: tuple) -> list[str]:
+    """The instructions of a compiled module that copy or concatenate
+    into a buffer of ``shape``."""
+    ring = "[" + ",".join(map(str, shape)) + "]"
+    moves = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = (.*?) ([\w-]+)\(", line)
+        if m and m.group(2) in ("copy", "copy-start", "copy-done",
+                                "concatenate") and ring in m.group(1):
+            moves.append(line.strip()[:120])
+    return moves
+
+
+@pytest.mark.parametrize("name", list(BENCH_STEPS))
+def test_slot_decode_step_keeps_ring_in_place(topo, on_tpu, name):
+    """At a benchmark configuration's widths, depth, slots and ring, the
+    slot decode step writes each layer's new rows into the stacked ring
+    where it lies: its temporaries stay under one layer's ring plus one
+    layer's weights, and nothing copies or concatenates a buffer of the
+    stacked ring's shape."""
+    base, layers, slots, capacity = BENCH_STEPS[name]
+    cfg = dataclasses.replace(base, num_layers=layers)
+    mesh = _mesh(topo, 1)
+    shapes = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+    params = _shapes(shapes, param_shardings(shapes, mesh, fsdp=True))
+    cache_shapes = jax.eval_shape(lambda: T.init_cache(cfg, T.CacheSpec(
+        capacity=capacity, batch=slots, per_slot=True)))
+    cache = _shapes(cache_shapes, cache_shardings(cache_shapes, mesh))
+    tok = jax.ShapeDtypeStruct((slots,), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    step = jax.jit(lambda p, t, c: T.decode_slots(p, cfg, t, c, mesh=mesh),
+                   donate_argnums=(2,))
+    compiled = step.lower(params, tok, cache).compile()
+
+    ring = cache_shapes["k"]
+    layer_ring = 2 * ring.size // layers * ring.dtype.itemsize     # k + v
+    layer_weights = sum(a.size * a.dtype.itemsize
+                        for a in jax.tree.leaves(shapes["blocks"])) // layers
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_ring + layer_weights, (temp, layer_ring,
+                                               layer_weights)
+    assert not _ring_moves(compiled.as_text(), ring.shape)

@@ -4,6 +4,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, PAPER_MODEL_IDS, get_config, \
@@ -79,6 +80,124 @@ def test_decode_matches_forward(arch, rng):
                                 - ld[:, 0].astype(jnp.float32))))
     scale = float(jnp.max(jnp.abs(full[:, -1]))) + 1e-9
     assert err / scale < 0.02, f"{arch}: decode diverges from forward"
+
+
+SLOT_ARCHS = [a for a in ARCH_IDS if a != "whisper_tiny"]   # no enc-dec slots
+
+
+def _slot_cache(params, cfg, prompts, capacity, chunk, kv_dtype):
+    """Prefill each row's prompt with ``chunk_prefill`` in chunks of
+    ``chunk`` into a request cache and insert it as slot r.  Returns the
+    per-slot cache and each prompt's last-token logits."""
+    step = jax.jit(lambda p, t, c: T.chunk_prefill(p, cfg, t, c))
+    spec = dict(capacity=capacity, kv_dtype=kv_dtype, per_slot=True)
+    cache = T.init_cache(cfg, T.CacheSpec(batch=len(prompts), **spec))
+    last = []
+    for r, prompt in enumerate(prompts):
+        rc = T.init_cache(cfg, T.CacheSpec(batch=1, **spec))
+        for a in range(0, len(prompt), chunk):
+            logits, rc = step(params, prompt[None, a:a + chunk], rc)
+        cache = T.cache_insert_slot(cache, jnp.int32(r), rc)
+        last.append(logits[0, -1])
+    return cache, last
+
+
+def _slots_against_forward(cfg, rng, kv_dtype, tol):
+    """Rows at different positions: chunked prefill, then decode steps,
+    every logit against ``forward_train`` while the prompts fit the ring."""
+    params = T.init_params(rng, cfg)
+    lens, new = (5, 9, 12), 4
+    tokens = jax.random.randint(rng, (len(lens), max(lens) + new), 0,
+                                cfg.vocab_size)
+    full = T.forward_train(params, cfg, tokens, remat=False)
+
+    def check(r, p, got):
+        ref = full[r, p].astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref)))
+        scale = float(jnp.max(jnp.abs(ref))) + 1e-9
+        assert err / scale < tol, (cfg.name, r, p, err / scale)
+
+    cache, last = _slot_cache(params, cfg, [tokens[r, :n] for r, n in
+                                            enumerate(lens)], 32, 4, kv_dtype)
+    for r, n in enumerate(lens):
+        check(r, n - 1, last[r])
+    step = jax.jit(lambda p, t, c: T.decode_slots(p, cfg, t, c))
+    for i in range(new):
+        tok = jnp.stack([tokens[r, n + i] for r, n in enumerate(lens)])
+        logits, cache = step(params, tok, cache)
+        for r, n in enumerate(lens):
+            check(r, n + i, logits[r, 0])
+
+
+def _slots_wrap_like_lockstep(rng):
+    """Sliding window past a full ring, the prompt's second chunk wrapping
+    inside it: the chunks lay the first layer's K/V (which depend on no
+    attention) where lock-step ``prefill`` does, and from the same ring
+    the slot step is bit-identical to lock-step ``decode_step``, logits
+    and cache."""
+    cfg = get_smoke_config("h2o_danube_1_8b")
+    params = T.init_params(rng, cfg)
+    c = cfg.sliding_window
+    toks = jax.random.randint(rng, (2, c + 5 + 2 * c), 0, cfg.vocab_size)
+    slots, _ = _slot_cache(params, cfg, list(toks[:, :c + 5]), c, c - 3,
+                           jnp.bfloat16)
+    _, filled = T.prefill(params, cfg, toks[:, :c + 5],
+                          T.init_cache(cfg, T.CacheSpec(capacity=c, batch=2)))
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(slots[key][0]),
+                                      np.asarray(filled[key][0]))
+    np.testing.assert_array_equal(np.asarray(slots["slot_pos"]),
+                                  np.tile(np.asarray(filled["slot_pos"]),
+                                          (2, 1)))
+    lock = {**slots, "pos": slots["pos"][0], "slot_pos": slots["slot_pos"][0]}
+    step = jax.jit(lambda p, t, c: T.decode_slots(p, cfg, t, c))
+    lock_step = jax.jit(lambda p, t, c: T.decode_step(p, cfg, t, c))
+    for t in range(c + 5, toks.shape[1]):
+        got, slots = step(params, toks[:, t], slots)
+        want, lock = lock_step(params, toks[:, t], lock)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(slots[key]),
+                                      np.asarray(lock[key]))
+    np.testing.assert_array_equal(np.asarray(slots["slot_pos"][0]),
+                                  np.asarray(lock["slot_pos"]))
+
+
+@pytest.mark.parametrize("case", SLOT_ARCHS + ["swa_ring_wrap", "int8_kv"])
+def test_slot_path_matches_reference(case, rng):
+    """The continuous-batching path (``chunk_prefill``, ``decode_slots``)
+    against references that do not run it: ``forward_train`` for every
+    family (MoE: dropless capacity), lock-step decode through a ring wrap,
+    and an int8 KV ring within ``test_int8_kv_cache_close_to_bf16``'s
+    tolerance."""
+    if case == "swa_ring_wrap":
+        _slots_wrap_like_lockstep(rng)
+        return
+    arch, kv_dtype, tol = case, jnp.bfloat16, 0.02
+    if case == "int8_kv":
+        arch, kv_dtype, tol = "qwen3_14b", jnp.int8, 0.15
+    cfg = get_smoke_config(arch)
+    if cfg.moe_experts:
+        cfg = dataclasses.replace(cfg,
+                                  moe_capacity_factor=float(cfg.moe_experts))
+    _slots_against_forward(cfg, rng, kv_dtype, tol)
+
+
+@pytest.mark.parametrize("t,first", [(1, 5), (4, 0), (4, 13), (7, 13),
+                                     (16, 9)])
+def test_ring_rows_written_in_place_like_a_scatter(t, first):
+    """``_put_rows`` writes each row's run of T tokens at its positions
+    modulo C, wrapping past the ring's end, exactly as a scatter would."""
+    L, B, H, C, D = 3, 2, 2, 16, 4
+    key = jax.random.PRNGKey(t * 100 + first)
+    buf = jax.random.normal(key, (L, B, H, C, D))
+    val = jax.random.normal(jax.random.fold_in(key, 1), (B, H, t, D))
+    positions = (jnp.array([[first], [first + 3 * C + 2]])
+                 + jnp.arange(t)[None, :])
+    want = buf.at[1, jnp.arange(B)[:, None], :, positions % C, :].set(
+        val.transpose(0, 2, 1, 3))
+    got = T._put_rows(buf, val, 1, positions)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_swa_ring_buffer_eviction(rng):

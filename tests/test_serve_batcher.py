@@ -2,6 +2,7 @@
 slot-addressable cache ops, chunked prefill, and the request scheduler's
 greedy parity with lock-step serving."""
 
+import dataclasses
 import warnings
 
 import jax
@@ -21,6 +22,37 @@ def _engine(mesh, cfg, rng, **kw):
     scfg = ServeConfig(**{"batch": 2, "cache_capacity": 64,
                           "prefill_chunk": 8, **kw})
     return ServeEngine(cfg, mesh, params, scfg)
+
+
+def _step_temps(step, per_slot: bool, layers: int) -> tuple[int, int]:
+    """(temporaries of the compiled one-token step, bytes of one layer's
+    K+V ring) for the smoke qwen3 config at ``layers`` layers with a
+    4096-token ring.  Held in float32: XLA's CPU backend computes bfloat16
+    dots in float32 and converts the whole stacked ring around each
+    layer's read (the TPU compiler does not: tests/test_chip_compile.py
+    bounds the bfloat16 step there)."""
+    cfg = dataclasses.replace(get_smoke_config("qwen3_14b"),
+                              param_dtype="float32", num_layers=layers)
+    params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, T.CacheSpec(
+        capacity=4096, batch=2, per_slot=per_slot, kv_dtype=jnp.float32)))
+    compiled = jax.jit(lambda p, t, c: step(p, cfg, t, c),
+                       donate_argnums=(2,)).lower(
+        params, jax.ShapeDtypeStruct((2,), jnp.int32), cache).compile()
+    ring = cache["k"]
+    return (compiled.memory_analysis().temp_size_in_bytes,
+            2 * ring.size // layers * ring.dtype.itemsize)
+
+
+def _assert_ring_stays(step, per_slot: bool) -> None:
+    """The step writes the stacked ring in place: its temporaries hold one
+    layer's worth (the CPU backend lays the layer it attends over out for
+    its dots, beside the scores), under one and a half layers' K+V ring
+    and the same at any depth."""
+    temps, layer_ring = _step_temps(step, per_slot, 2)
+    assert temps < 1.5 * layer_ring, (temps, layer_ring)
+    assert _step_temps(step, per_slot, 6)[0] == temps
 
 
 class TestDonation:
@@ -48,6 +80,7 @@ class TestDonation:
 
         hlo = eng._decode.lower(eng.params, tok, cache2).compile().as_text()
         assert "input_output_alias" in hlo
+        _assert_ring_stays(T.decode_step, per_slot=False)
 
     def test_slot_step_is_copy_free(self, mesh11, rng):
         cfg = get_smoke_config("qwen3_14b")
@@ -58,6 +91,7 @@ class TestDonation:
         eng.step(jnp.zeros((2,), jnp.int32))
         jax.block_until_ready(eng.slot_cache)
         assert eng.slot_cache["k"].unsafe_buffer_pointer() == k_ptr
+        _assert_ring_stays(T.decode_slots, per_slot=True)
 
 
 class TestSlotCacheOps:
