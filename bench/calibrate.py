@@ -69,7 +69,7 @@ def main() -> None:
     args = ap.parse_args()
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-    from bench import harness, model, traffic as T
+    from bench import families, harness, model, traffic as T
 
     cell = {w["name"]: w for w in harness.load_benchmark()["workloads"]}[
         args.workload]
@@ -78,8 +78,7 @@ def main() -> None:
     hf = model.load_config(cell["config"])
     mix = T.load_mix(cell["traffic"])
     limits = harness.load_limits(args.workload)
-    ref = __import__(f"bench.references.{hf['reference']}",
-                     fromlist=["CONTROLS"])
+    ref = families.reference(hf)
     split: dict = {}
     eng = harness.build_program(hf, args.seeds[0], False, split)
     for k, seed in enumerate(args.seeds):

@@ -21,8 +21,8 @@
 
 from __future__ import annotations
 
+import functools
 import gc
-import importlib
 import json
 import math
 import shutil
@@ -33,7 +33,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import counts
+from bench import families
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -217,6 +217,11 @@ class Runner:
         self.n_done = 0
         self.t0 = clock()
 
+    @functools.cached_property
+    def chunk_flops(self):
+        """The useful FLOPs of a chunk, as the family adapter counts them."""
+        return families.adapter(self.hf).chunk_flops
+
     def now(self) -> float:
         return self.clock() - self.t0
 
@@ -285,7 +290,7 @@ class Runner:
                 rid, off = pf_at
             else:
                 rid, off = next(iter(queued - {r.rid for r in b.queue})), 0
-            flops += counts.chunk_flops(
+            flops += self.chunk_flops(
                 hf, off, t, head=off + t == self.prompt_len[rid])
         made = {st.req.rid: len(st.generated) for st in b.active.values()}
         for c in b.completed[self.n_done:]:
@@ -298,7 +303,7 @@ class Runner:
             self.win.stamp(rid, end, n - old)
             for j in range(max(old, 1), n):     # token 0 comes from prefill
                 decoded += 1
-                flops += counts.chunk_flops(
+                flops += self.chunk_flops(
                     hf, self.prompt_len[rid] + j - 1, 1, head=True)
             self.known[rid] = n
         self.win.ticks.append(Tick(start, end, decoded, t, flops))
@@ -459,11 +464,12 @@ def check(hf: dict, seed: int, seqs, limits: dict, controls=(),
     program and for each control of ``controls`` put in its place.
     Returns ``{"program" or control: (ok, checks)}``; ``keep`` (a dict)
     receives the reference's readings, its own under ``"reference"``."""
-    ref = importlib.import_module(f"bench.references.{hf['reference']}")
+    ref = families.reference(hf)
     if not seqs:
         return {k: judge([], [], [], limits)
                 for k in ("program",) + tuple(controls)}
-    idx = probe_entries(seed, hf["vocab_size"])
+    vocab = families.adapter(hf).model_config(hf).vocab_size
+    idx = probe_entries(seed, vocab)
     out = ref.served_gaps(hf, seed, [(t, f) for t, f, _ in seqs], idx,
                           controls=controls)
     if keep is not None:

@@ -1,10 +1,13 @@
 """The system under test, built from a configuration file.
 
-This is the one place where the benchmark speaks the program's language:
-the published config keys become a ``repro`` ``ModelConfig``, the seeded
-weights of ``bench/weights.py`` become the program's parameter tree, and
-the engine is the program's own ``ServeEngine`` with the serving knobs the
-program's planner picks (``elk_serve_config``) for a one-chip mesh.
+What differs from one architecture to the next is the configuration's
+family adapter (``bench/families``, found by its ``"family"`` key): it
+maps the published config keys to a ``repro`` ``ModelConfig`` and lays
+the seeded draws out as the program's parameter tree.  What is left here
+is the same for every family: the engine is the program's own
+``ServeEngine`` with the serving knobs the program's planner picks
+(``elk_serve_config``) for a one-chip mesh, and its weights are made on
+the device in one compiled call from the seed.
 """
 
 from __future__ import annotations
@@ -14,8 +17,8 @@ import time
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 
+from bench import families
 from bench import weights as W
 
 BENCH = Path(__file__).resolve().parent
@@ -30,58 +33,14 @@ def load_config(name: str) -> dict:
 
 
 def model_config(hf: dict):
-    """The program's ``ModelConfig`` with every published size of ``hf``."""
-    from repro.models.config import ModelConfig
-
-    if hf.get("architectures") not in (["Qwen3ForCausalLM"],
-                                       ["MistralForCausalLM"],
-                                       ["LlamaForCausalLM"]):
-        raise ValueError(f"no program mapping for {hf.get('architectures')}")
-    if hf["hidden_act"] != "silu" or hf.get("tie_word_embeddings"):
-        raise ValueError("only untied SwiGLU decoders are mapped")
-    z = W.dims(hf)
-    window = W.window(hf)
-    return ModelConfig(
-        name=hf["model_type"], family="dense", num_layers=z["layers"],
-        d_model=z["d"], num_heads=z["h"], num_kv_heads=z["kv"],
-        d_ff=z["ff"], vocab_size=z["v"], head_dim=z["hd"],
-        qk_norm=W.qk_norm(hf), gated_mlp=True, mlp_act="silu",
-        norm_eps=float(hf["rms_norm_eps"]),
-        rope_theta=float(hf["rope_theta"]),
-        sliding_window=window, swa_layers="all" if window else "none",
-        param_dtype="bfloat16")
+    """The program's ``ModelConfig`` for ``hf``, from its family adapter."""
+    return families.adapter(hf).model_config(hf)
 
 
 def program_params(base: jax.Array, hf: dict, cfg):
-    """The program's parameter tree holding ``bench/weights.py``'s draws
-    for the key ``base``.  Traceable, so that it runs on the device."""
-    from repro.models import transformer as tfm
-
-    outer = W.outer_weights(base, hf)
-    layers = jax.vmap(lambda i: W.layer_weights(base, i, hf))(
-        jnp.arange(cfg.num_layers))
-
-    def attn(w):
-        a = {"w_q": w["q"], "w_k": w["k"], "w_v": w["v"], "w_o": w["o"]}
-        if "q_norm" in w:
-            a["q_norm"], a["k_norm"] = w["q_norm"], w["k_norm"]
-        return a
-
-    params = {
-        "embed": outer["embed"], "final_norm": outer["final_norm"],
-        "lm_head": outer["lm_head"], "prefix": [],
-        "blocks": [{"ln1": layers["attn_norm"], "ln2": layers["mlp_norm"],
-                    "attn": attn(layers),
-                    "mlp": {"w_gate": layers["gate"], "w_up": layers["up"],
-                            "w_down": layers["down"]}}],
-    }
-    want = jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
-    got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
-    if jax.tree.structure(want) != jax.tree.structure(params) or got != \
-            jax.tree.map(lambda a: (a.shape, a.dtype), want):
-        raise ValueError("the program's parameter layout changed; "
-                         "bench/model.py no longer matches it")
-    return params
+    """The program's parameter tree for the key ``base``, from ``hf``'s
+    family adapter.  Traceable, so that it runs on the device."""
+    return families.adapter(hf).program_params(base, hf, cfg)
 
 
 def build_engine(seed: int, hf: dict, cfg, mesh, split: dict | None = None):

@@ -1,12 +1,13 @@
-"""Operations and bytes at published widths, against hand-computed values,
-and shares that stay within 100% on a synthetic trace as fast as the
-chip's peaks allow."""
+"""Operations and bytes at published widths, against hand-computed values
+(the dense family adapter's token FLOPs among them), and shares that stay
+within 100% on a synthetic trace as fast as the chip's peaks allow."""
 
 import math
 
 import pytest
 
 from bench import counts, model, trace_reduce as R
+from bench.families import dense_gqa
 from bench.metrics import RunData, fused_mlp_roofline, mfu
 from bench.peaks import peaks
 from bench.window import Tick, Window
@@ -46,11 +47,11 @@ def test_token_flops_qwen3_by_hand():
                      + 3 * 5120 * 17408)
     assert per_layer == 660_602_880
     # the first prompt token (context 1) with the head
-    assert counts.chunk_flops(hf, 0, 1, head=True) == (
+    assert dense_gqa.chunk_flops(hf, 0, 1, head=True) == (
         12 * (660_602_880 + 4 * 40 * 128) + 2 * 5120 * 151936)
     # a 128-token chunk from position 1000: context 1001..1128, no head
     attn = 4 * 40 * 128 * sum(range(1001, 1129))
-    assert counts.chunk_flops(hf, 1000, 128, head=False) == 12 * (
+    assert dense_gqa.chunk_flops(hf, 1000, 128, head=False) == 12 * (
         128 * 660_602_880 + attn)
 
 
@@ -59,9 +60,9 @@ def test_token_flops_danube_window_by_hand():
     per_layer = 2 * (2560 * 2560 * 2 + 2 * 2560 * 8 * 80 + 3 * 2560 * 6912)
     assert per_layer == 138_936_320
     # position 9999 sees only the 4096-token window
-    assert counts.chunk_flops(hf, 9999, 1, head=True) == (
+    assert dense_gqa.chunk_flops(hf, 9999, 1, head=True) == (
         24 * (138_936_320 + 4 * 32 * 80 * 4096) + 2 * 2560 * 32000)
-    assert counts.chunk_flops(hf, 99, 1, head=False) == 24 * (
+    assert dense_gqa.chunk_flops(hf, 99, 1, head=False) == 24 * (
         138_936_320 + 4 * 32 * 80 * 100)
 
 

@@ -9,6 +9,7 @@ import pytest
 
 from bench import harness, model, traffic
 from bench import weights as W
+from bench.families import dense_gqa
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -39,7 +40,11 @@ def test_cell_files_resolve(cell):
     hf = model.load_config(w["config"])
     assert traffic.load_mix(w["traffic"])
     assert set(harness.load_limits(cell)) >= {"logit_gap", "min_tokens"}
-    importlib.import_module(f"bench.references.{hf['reference']}")
+    adapter = importlib.import_module(f"bench.families.{hf['family']}")
+    for fn in ("model_config", "program_params", "chunk_flops"):
+        assert callable(getattr(adapter, fn))
+    ref = importlib.import_module(f"bench.references.{hf['family']}")
+    assert callable(ref.served_gaps)
     for m in harness.cell_metrics(BENCH, cell, trace=True):
         reader = importlib.import_module(
             f"bench.metrics.{m['name'].split('.')[0]}")
@@ -57,14 +62,28 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer(cell):
 @pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_file_as_run(conf):
     """The file holds the configuration as it runs: every cut is listed in
-    ``reduced``, and no width is cut."""
+    ``reduced``, no width is cut, and its family's adapter maps it."""
     hf = json.loads((ROOT / conf["file"]).read_text())
     assert hf["source"] == conf["source"]
     assert sorted(hf["reduced"]) == sorted(conf["reduced"])
     for key, cut in hf["reduced"].items():
         assert hf[key] == cut["here"] and not key.endswith(("_dim", "_size"))
     cfg = model.model_config(hf)
+    assert (cfg.d_model, cfg.num_layers, cfg.vocab_size) == (
+        hf["hidden_size"], hf["num_hidden_layers"], hf["vocab_size"])
+
+
+DENSE = [c for c in BENCH["configs"] if json.loads(
+    (ROOT / c["file"]).read_text())["family"] == "dense_gqa"]
+
+
+@pytest.mark.parametrize("conf", DENSE, ids=lambda c: c["name"])
+def test_dense_gqa_adapter_maps_every_width(conf):
+    """The dense family's adapter gives the program every published width."""
+    hf = json.loads((ROOT / conf["file"]).read_text())
+    cfg = dense_gqa.model_config(hf)
     z = W.dims(hf)
     assert (cfg.d_model, cfg.d_ff, cfg.resolved_head_dim) == (
         z["d"], z["ff"], z["hd"])
+    assert (cfg.num_heads, cfg.num_kv_heads) == (z["h"], z["kv"])
     assert cfg.norm_eps == hf["rms_norm_eps"]

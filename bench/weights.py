@@ -1,12 +1,15 @@
-"""Random weights of a dense GQA decoder, drawn from a run's seed.
+"""Random weights drawn from a run's seed: the generic draw, and the
+draws of a dense GQA decoder.
 
 Both sides of the comparison draw from here: the program's parameters
-(``bench/model.py`` lays them out the way the program wants them) and the
-plain reference (``bench/references``), which draws each layer again on
-its own.  Every tensor has its own key, folded from the seed, the layer and
-the tensor's name, and is drawn directly in bfloat16 (the dtype the model
-is served in), so a layer drawn alone is bit for bit the layer inside the
-stacked parameters.
+(``bench/families/dense_gqa.py`` lays them out the way the program wants
+them) and the plain reference (``bench/references/dense_gqa.py``), which
+draws each layer again on its own.  Every tensor has its own key, folded
+from the seed, the layer and the tensor's name, and is drawn directly in
+bfloat16 (the dtype the model is served in) by ``draw``, so a layer drawn
+alone is bit for bit the layer inside the stacked parameters.  A family
+with other tensors draws them with ``draw`` in a module of its own, which
+its adapter and its reference both import.
 
 Norm weights are ``1 + delta`` with a small random ``delta``: the program
 stores ``delta`` (its norms scale by ``1 + w``), the reference multiplies by
@@ -70,35 +73,40 @@ def _normal(key, shape, scale):
     return jax.random.normal(key, shape, DTYPE) * jnp.asarray(scale, DTYPE)
 
 
+def draw(base: jax.Array, layer, name: str, shape, scale) -> jax.Array:
+    """The tensor ``name`` of ``layer`` (-1 outside the layers): a normal
+    draw in bfloat16 from its own key, times ``scale``."""
+    return _normal(_key(base, layer, name), shape, scale)
+
+
 def layer_weights(base: jax.Array, layer, hf: dict) -> dict:
     """One decoder layer, bfloat16, matrices as (in, out)."""
     z = dims(hf)
     d, h, kv, hd, ff = z["d"], z["h"], z["kv"], z["hd"], z["ff"]
     out = (2 * z["layers"]) ** -0.5      # residual-branch scale
     w = {
-        "attn_norm": _normal(_key(base, layer, "attn_norm"), (d,), NORM_DELTA),
-        "mlp_norm": _normal(_key(base, layer, "mlp_norm"), (d,), NORM_DELTA),
-        "q": _normal(_key(base, layer, "q"), (d, h * hd), d ** -0.5),
-        "k": _normal(_key(base, layer, "k"), (d, kv * hd), d ** -0.5),
-        "v": _normal(_key(base, layer, "v"), (d, kv * hd), d ** -0.5),
-        "o": _normal(_key(base, layer, "o"), (h * hd, d),
-                     out * (h * hd) ** -0.5),
-        "gate": _normal(_key(base, layer, "gate"), (d, ff), d ** -0.5),
-        "up": _normal(_key(base, layer, "up"), (d, ff), d ** -0.5),
-        "down": _normal(_key(base, layer, "down"), (ff, d), out * ff ** -0.5),
+        "attn_norm": draw(base, layer, "attn_norm", (d,), NORM_DELTA),
+        "mlp_norm": draw(base, layer, "mlp_norm", (d,), NORM_DELTA),
+        "q": draw(base, layer, "q", (d, h * hd), d ** -0.5),
+        "k": draw(base, layer, "k", (d, kv * hd), d ** -0.5),
+        "v": draw(base, layer, "v", (d, kv * hd), d ** -0.5),
+        "o": draw(base, layer, "o", (h * hd, d), out * (h * hd) ** -0.5),
+        "gate": draw(base, layer, "gate", (d, ff), d ** -0.5),
+        "up": draw(base, layer, "up", (d, ff), d ** -0.5),
+        "down": draw(base, layer, "down", (ff, d), out * ff ** -0.5),
     }
     if qk_norm(hf):
-        w["q_norm"] = _normal(_key(base, layer, "q_norm"), (hd,), NORM_DELTA)
-        w["k_norm"] = _normal(_key(base, layer, "k_norm"), (hd,), NORM_DELTA)
+        w["q_norm"] = draw(base, layer, "q_norm", (hd,), NORM_DELTA)
+        w["k_norm"] = draw(base, layer, "k_norm", (hd,), NORM_DELTA)
     return w
 
 
 def outer_weights(base: jax.Array, hf: dict) -> dict:
-    """Token embedding (V, d), final norm delta (d,), output head (d, V)."""
-    z = dims(hf)
-    d, v = z["d"], z["v"]
+    """Token embedding (V, d), final norm delta (d,), output head (d, V):
+    the same for every family, from ``hidden_size`` and ``vocab_size``."""
+    d, v = hf["hidden_size"], hf["vocab_size"]
     return {
-        "embed": _normal(_key(base, -1, "embed"), (v, d), d ** -0.5),
-        "final_norm": _normal(_key(base, -1, "final_norm"), (d,), NORM_DELTA),
-        "lm_head": _normal(_key(base, -1, "lm_head"), (d, v), d ** -0.5),
+        "embed": draw(base, -1, "embed", (v, d), d ** -0.5),
+        "final_norm": draw(base, -1, "final_norm", (d,), NORM_DELTA),
+        "lm_head": draw(base, -1, "lm_head", (d, v), d ** -0.5),
     }
