@@ -231,6 +231,9 @@ def _attn_layer(ops: list[Op], cfg: ModelConfig, li: int, rows: int,
         eff_ctx = actx
 
     ops.append(_vec(f"l{li}.ln1", li, rows, d))
+    if cfg.mla:
+        _mla_attn(ops, cfg, li, rows, eff_ctx, batch, phase, dt)
+        return
     ops.append(_mm(f"l{li}.q", li, rows, nq * hd, d, bias=cfg.qkv_bias))
     ops.append(_mm(f"l{li}.kv", li, rows, 2 * nkv * hd, d, bias=cfg.qkv_bias))
     extra = 4.0 if cfg.qk_norm else 2.0  # rope (+qk rmsnorm)
@@ -244,6 +247,36 @@ def _attn_layer(ops: list[Op], cfg: ModelConfig, li: int, rows: int,
     ops.append(_bmm_attn(f"l{li}.attnv", li, rows, nq, eff_ctx, hd,
                          kv_bytes, kv_hbm=kv_hbm, score=False, dt=dt))
     ops.append(_mm(f"l{li}.o", li, rows, d, nq * hd))
+
+
+def _mla_attn(ops: list[Op], cfg: ModelConfig, li: int, rows: int,
+              eff_ctx: int, batch: int, phase: Phase, dt: int) -> None:
+    """Latent attention, absorbed (DeepSeek-V3): low-rank q (q_a, its
+    norm, q_b), the joint latent + rope-key projection (kv_a) and the
+    latent's norm, each head's key up-projection folded into its query
+    (uk), scores and values over the latent cache of ``latent + rope``
+    bytes a token shared by every head, each head's value up-projection
+    (uv) and o."""
+    d, nq = cfg.d_model, cfg.num_heads
+    rq, rkv, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    ops.append(_mm(f"l{li}.q_a", li, rows, rq, d))
+    ops.append(_vec(f"l{li}.q_norm", li, rows, rq))
+    ops.append(_mm(f"l{li}.q", li, rows, nq * (nope + rope), rq))
+    ops.append(_mm(f"l{li}.kv_a", li, rows, rkv + rope, d))
+    ops.append(_vec(f"l{li}.kv_norm", li, rows, rkv))
+    ops.append(_vec(f"l{li}.rope", li, rows, (nq + 1) * rope,
+                    flop_mult=2.0))
+    ops.append(_mm(f"l{li}.uk", li, rows, nq * rkv, nope))
+    kv_bytes = batch * eff_ctx * (rkv + rope) * dt
+    kv_hbm = phase == "decode"
+    ops.append(_bmm_attn(f"l{li}.score", li, rows, nq, eff_ctx, rkv + rope,
+                         kv_bytes, kv_hbm=kv_hbm, score=True, dt=dt))
+    ops.append(_vec(f"l{li}.softmax", li, rows * nq, eff_ctx, flop_mult=6.0))
+    ops.append(_bmm_attn(f"l{li}.attnv", li, rows, nq, eff_ctx, rkv,
+                         kv_bytes, kv_hbm=kv_hbm, score=False, dt=dt))
+    ops.append(_mm(f"l{li}.uv", li, rows, nq * dv, rkv))
+    ops.append(_mm(f"l{li}.o", li, rows, d, nq * dv))
 
 
 def _ssm_branch(ops: list[Op], cfg: ModelConfig, li: int, rows: int,
@@ -283,11 +316,13 @@ def _mlp(ops: list[Op], cfg: ModelConfig, li: int, rows: int, dt: int) -> None:
         mff = cfg.moe_hidden()
         router_idx = len(ops)
         ops.append(_mm(f"l{li}.router", li, rows, e, d))
-        # tokens*topk rows through touched experts; weights = touched experts
-        touched = min(e, rows * k)
+        # tokens*topk rows through touched experts; weights = touched
+        # experts.  A held share gets its share of the routed rows.
+        held = cfg.experts_held
+        m = -(-rows * k * held // e)
+        touched = min(held, m)
         nmat = 3 if cfg.gated_mlp else 2
         w_bytes = touched * nmat * d * mff * dt
-        m = rows * k
         flops = 2.0 * m * nmat * d * mff
         inputs = (TensorSpec("x", (0, 2), m * d * dt, False),
                   TensorSpec("w_experts", (2, 1), w_bytes, True))

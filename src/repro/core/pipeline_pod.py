@@ -204,7 +204,7 @@ _SHARD_RULES: dict[str, tuple[int, bool]] = {
     # column-parallel matmuls (QKV/head projections, up-projections)
     **{s: (1, False) for s in ("q", "kv", "qkv", "xq", "fc1", "gate_up",
                                "shared_up", "cm_k", "ssm_in",
-                               "r", "k", "v", "g")},
+                               "r", "k", "v", "g", "uk", "uv")},
     # row-parallel matmuls (output/down projections -> all-reduce)
     **{s: (2, False) for s in ("o", "xo", "out", "fc2", "down",
                                "shared_down", "cm_v", "ssm_out")},
@@ -215,8 +215,9 @@ _SHARD_RULES: dict[str, tuple[int, bool]] = {
                               "wkv", "ssm_scan")},
     **{s: (0, True) for s in ("softmax", "xsoftmax")},
 }
-# replicated: ln*/router/embed/final_norm/lm_head/vision_patches — cheap,
-# and their inputs arrive replicated after the preceding all-reduce.
+# replicated: ln*/router/embed/final_norm/lm_head/vision_patches and MLA's
+# low-rank q_a/kv_a with their norms — cheap, and their inputs arrive
+# replicated after the preceding all-reduce.
 
 
 def _ceil_div(a: int, b: int) -> int:

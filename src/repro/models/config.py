@@ -16,6 +16,18 @@ Family = Literal["dense", "moe", "ssm", "hybrid", "audio", "vlm"]
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling, as deepseek-v3's ``DeepseekV3YarnRotaryEmbedding``
+    computes it."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: Family
@@ -48,6 +60,25 @@ class ModelConfig:
     moe_offset: int = 0
     moe_first_dense: int = 0     # first k layers dense (kimi/deepseek style)
     moe_capacity_factor: float = 1.25
+    # router: "softmax" over the top k, or "sigmoid" scores (deepseek-v3 /
+    # kimi-k2 ``noaux_tc``: a selection bias that picks, not weighs, the
+    # top k, whose scores are renormalised and scaled by
+    # ``moe_routed_scale``)
+    moe_router: Literal["softmax", "sigmoid"] = "softmax"
+    moe_routed_scale: float = 1.0
+    # expert parallelism: this chip holds experts [offset, offset + held)
+    # of the router's ``moe_experts`` (0 -> all of them)
+    moe_experts_held: int = 0
+    moe_held_offset: int = 0
+
+    # --- latent attention (MLA: deepseek-v3 / kimi-k2) --------------------
+    q_lora_rank: int = 0         # low-rank q (RMS norm after q_a)
+    kv_lora_rank: int = 0        # >0 -> MLA: a latent KV ring of this width
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0    # one rope key shared by every head
+    v_head_dim: int = 0
+
+    rope_yarn: Optional[Yarn] = None     # None -> plain rope
 
     # --- SSM / RWKV / hybrid --------------------------------------------
     ssm_state: int = 0           # mamba-style state size per channel
@@ -76,6 +107,15 @@ class ModelConfig:
     @property
     def attn_free(self) -> bool:
         return self.rwkv
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this config holds."""
+        return self.moe_experts_held or self.moe_experts
 
     @property
     def resolved_head_dim(self) -> int:
@@ -115,7 +155,9 @@ class ModelConfig:
                 total += 5 * d * d + 4 * d
                 total += 2 * d * ff                     # channel mix (k, v)
                 continue
-            if self.num_heads:
+            if self.mla:
+                total += self.mla_param_count()
+            elif self.num_heads:
                 total += d * (nq * hd) + (nq * hd) * d  # q, o
                 total += 2 * d * (nkv * hd)             # k, v
                 if self.qkv_bias:
@@ -124,11 +166,13 @@ class ModelConfig:
                 # mamba branch: in-proj (x,z), dt/B/C proj, out-proj
                 total += 2 * d * d + d * (2 * self.ssm_state + d // 16) + d * d
             if self.is_moe_layer(i):
-                e = self.moe_experts if not active_only else self.moe_top_k
+                e = self.experts_held if not active_only else self.moe_top_k
                 mff = self.moe_hidden()
                 nmat = 3 if self.gated_mlp else 2
                 total += e * nmat * d * mff
                 total += d * self.moe_experts           # router (always dense)
+                if self.moe_router == "sigmoid":
+                    total += self.moe_experts           # selection bias
                 if self.moe_shared_d_ff:
                     total += nmat * d * self.moe_shared_d_ff
             else:
@@ -141,6 +185,18 @@ class ModelConfig:
         if enc:  # decoder cross-attention
             total += self.num_layers * 4 * d * d
         return int(total)
+
+    def mla_param_count(self) -> int:
+        """One layer's latent-attention weights: q_a (+ its norm), q_b,
+        the joint kv_a (latent and rope key, + the latent's norm), kv_b
+        (the per-head up-projections of keys and values) and o."""
+        d, nq = self.d_model, self.num_heads
+        rq, rkv = self.q_lora_rank, self.kv_lora_rank
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        q = d * rq + rq + rq * nq * qk
+        kv = d * (rkv + self.qk_rope_head_dim) + rkv
+        kv += rkv * nq * (self.qk_nope_head_dim + self.v_head_dim)
+        return q + kv + nq * self.v_head_dim * d
 
     def active_param_count(self) -> int:
         return self.param_count(active_only=True)

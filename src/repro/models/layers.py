@@ -14,14 +14,16 @@ fp32-accumulation, and written so XLA GSPMD can shard them along the
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-from repro.models.config import ModelConfig
+from repro.models.config import ModelConfig, Yarn
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -50,13 +52,49 @@ def _act(name: str):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_tables(positions: jax.Array, head_dim: int,
-                theta: float) -> tuple[jax.Array, jax.Array]:
-    """(sin, cos) tables for integer ``positions`` (any leading shape)."""
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_find_correction_range(low_rot: float, high_rot: float, dim: int,
+                               base: float, max_pos: int) -> tuple[int, int]:
+    """The rope dims between which yarn ramps from interpolated to
+    original frequencies: those that turn ``low_rot`` / ``high_rot`` times
+    over ``max_pos`` positions."""
+    def corr(rot):
+        return dim * math.log(max_pos / (rot * 2 * math.pi)) / (
+            2 * math.log(base))
+    return max(math.floor(corr(low_rot)), 0), min(math.ceil(corr(high_rot)),
+                                                 dim - 1)
+
+
+def yarn_linear_ramp_mask(lo: float, hi: float, n: int) -> np.ndarray:
+    if lo == hi:
+        hi += 0.001          # as the source: no division by zero
+    return np.clip((np.arange(n, dtype=np.float32) - lo) / (hi - lo), 0, 1)
+
+
+def rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                yarn: Optional[Yarn] = None) -> tuple[jax.Array, jax.Array]:
+    """(sin, cos) tables for integer ``positions`` (any leading shape).
+    With ``yarn``, dims past the correction range take their frequency
+    divided by the factor (at every position), and both tables are scaled
+    by the ratio of the two mscales."""
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    scale = 1.0
+    if yarn is not None:
+        lo, hi = yarn_find_correction_range(yarn.beta_fast, yarn.beta_slow,
+                                            head_dim, theta,
+                                            yarn.original_max)
+        keep = 1.0 - yarn_linear_ramp_mask(lo, hi, half)
+        freqs = freqs / yarn.factor * (1.0 - keep) + freqs * keep
+        scale = (yarn_get_mscale(yarn.factor, yarn.mscale)
+                 / yarn_get_mscale(yarn.factor, yarn.mscale_all_dim))
     ang = positions.astype(jnp.float32)[..., None] * freqs   # (..., half)
-    return jnp.sin(ang), jnp.cos(ang)
+    if scale == 1.0:
+        return jnp.sin(ang), jnp.cos(ang)
+    return jnp.sin(ang) * scale, jnp.cos(ang) * scale
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:

@@ -1,5 +1,9 @@
 """Mixture-of-experts FFN (llama4-maverick, kimi-k2).
 
+Two layers: ``moe_ffn`` (softmax over the top k, every expert held) and
+``held_moe_ffn`` (kimi-k2's sigmoid router over every expert, computing
+only the experts this chip holds).
+
 GShard-style capacity-factor einsum dispatch: shardable under GSPMD with the
 expert dimension on the ``model``/``expert`` mesh axis, no ragged ops, and a
 fixed compute shape (required for the multi-pod dry-run).  Tokens over
@@ -53,6 +57,9 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
     O(E*C*d + T*k*d)."""
     t, d = x.shape
     e, k = cfg.moe_experts, cfg.moe_top_k
+    if cfg.experts_held != e:
+        raise ValueError("the softmax router computes every expert; "
+                         "a held share needs the sigmoid router")
     cf = capacity_factor or cfg.moe_capacity_factor
     c = t * k if dropless else capacity(t, e, k, cf)
 
@@ -98,16 +105,67 @@ def moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig,
     return out.astype(x.dtype)
 
 
+@jax.named_scope("router")
+def sigmoid_route(x: jax.Array, p: dict, cfg: ModelConfig
+                  ) -> tuple[jax.Array, jax.Array]:
+    """Deepseek-v3's ``noaux_tc`` routing over every expert: float32
+    logits, sigmoid scores, the top k of scores plus the selection bias
+    (``router_bias``, which picks experts but never weighs them), their
+    scores renormalised over the k and scaled by ``moe_routed_scale``.
+
+    x: (T, d) -> weights (T, k) fp32, idx (T, k) int32."""
+    logits = jnp.einsum("td,de->te", x, p["router"],
+                        preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["router_bias"].astype(jnp.float32),
+                           cfg.moe_top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.moe_routed_scale, idx
+
+
+@jax.named_scope("moe")
+def held_moe_ffn(x: jax.Array, p: dict, cfg: ModelConfig
+                 ) -> tuple[jax.Array, jax.Array]:
+    """The routed experts' part of the layer that this chip holds: experts
+    ``[moe_held_offset, + moe_experts_held)`` of the ``moe_experts`` the
+    router scores (expert parallelism without its exchange: assignments
+    to experts held elsewhere add nothing here).
+
+    x: (T, d); p: router (d, E), router_bias (E,), w_gate/w_up (E_held,
+    d, ff), w_down (E_held, ff, d).  A token picks distinct experts, so
+    each held expert's buffer has T rows, the exact dropless bound: row t
+    holds token t where it is routed there and zeros elsewhere.  Returns
+    (out (T, d), the rows routed to each held expert (E_held,) int32)."""
+    held = cfg.experts_held
+    w, idx = sigmoid_route(x, p, cfg)
+    local = idx - cfg.moe_held_offset                         # (T, k)
+    mine = jax.nn.one_hot(local, held, dtype=jnp.float32)     # (T, k, Eh)
+    gates = jnp.einsum("tk,tke->et", w, mine)                 # (Eh, T)
+    routed = jnp.sum(mine, axis=1).T > 0                      # (Eh, T)
+    with jax.named_scope("dispatch"):
+        xe = jnp.where(routed[..., None], x[None], jnp.zeros((), x.dtype))
+    act = _act(cfg.mlp_act)
+    g = jnp.einsum("etd,edf->etf", xe, p["w_gate"])
+    u = jnp.einsum("etd,edf->etf", xe, p["w_up"])
+    ye = jnp.einsum("etf,efd->etd", act(g) * u, p["w_down"])
+    with jax.named_scope("combine"):
+        out = jnp.einsum("et,etd->td", gates, ye.astype(jnp.float32))
+    return out.astype(x.dtype), jnp.sum(routed, axis=1, dtype=jnp.int32)
+
+
 def moe_params(rng, cfg: ModelConfig, dtype) -> dict:
-    d, e = cfg.d_model, cfg.moe_experts
+    d, e, held = cfg.d_model, cfg.moe_experts, cfg.experts_held
     ff = cfg.moe_hidden()
     ks = jax.random.split(rng, 4)
     s_in, s_ff = d ** -0.5, ff ** -0.5
     p = {"router": jax.random.normal(ks[0], (d, e), dtype) * s_in}
+    if cfg.moe_router == "sigmoid":
+        p["router_bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.gated_mlp:
-        p["w_gate"] = jax.random.normal(ks[1], (e, d, ff), dtype) * s_in
-    p["w_up"] = jax.random.normal(ks[2], (e, d, ff), dtype) * s_in
-    p["w_down"] = jax.random.normal(ks[3], (e, ff, d), dtype) * s_ff
+        p["w_gate"] = jax.random.normal(ks[1], (held, d, ff), dtype) * s_in
+    p["w_up"] = jax.random.normal(ks[2], (held, d, ff), dtype) * s_in
+    p["w_down"] = jax.random.normal(ks[3], (held, ff, d), dtype) * s_ff
     return p
 
 

@@ -28,8 +28,15 @@ beyond — full-attention serving sizes ``C = seq_len``, SWA archs size
 Every op carries a named scope in its metadata, so a profiler trace can
 split a step by them: ``layer`` (one decoder layer), ``attn``, ``mlp``,
 ``head``, and ``kv_ring`` for every read and write of the cache (a
-layer's slice, the in-place writes of new K/V and state, and the position
-tags).
+layer's slice, the in-place writes of new K/V, latents and state, and the
+position tags); a held share of sigmoid-routed experts runs under ``mlp``
+as ``moe`` (``router``, ``dispatch``, ``combine`` inside) and
+``shared_expert``.
+
+Latent attention (MLA: kimi-k2, deepseek-v3) caches one normed latent and
+one rope key per token, shared by every head (``ckv``/``kpe``), and
+attends absorbed: each head's key and value up-projections are folded
+into its query and its output, in every mode.
 """
 
 from __future__ import annotations
@@ -49,10 +56,11 @@ from repro.models.config import ModelConfig
 from repro.models.layers import (AttnSpec, apply_rope, attn_mask_bias,
                                  chunked_gqa_attention, gqa_attention,
                                  linear, mlp, mlp_params, qk_head_norm,
-                                 rms_norm, rope_tables, softmax_xent)
+                                 rms_norm, rope_tables, softmax_xent,
+                                 yarn_get_mscale)
 
 PyTree = Any
-_POS_SENTINEL = jnp.int32(2 ** 30)   # cache slots not yet written
+_POS_SENTINEL = np.int32(2 ** 30)    # cache slots not yet written
 
 
 # ---------------------------------------------------------------------------
@@ -122,13 +130,39 @@ def _attn_params(rng, cfg: ModelConfig, dtype, cross: bool = False) -> dict:
     return p
 
 
+def _mla_params(rng, cfg: ModelConfig, dtype) -> dict:
+    """Latent attention: q through a low-rank ``w_qa`` (d, rq), its norm and
+    ``w_qb`` (rq, H, nope + rope); ``w_kva`` (d, latent + rope) gives the
+    latent (normed by ``kv_norm``) and the shared rope key; ``w_uk`` (H,
+    nope, latent) and ``w_uv`` (H, v, latent) are the heads' key and value
+    up-projections (the source's ``kv_b_proj``, per head), ``w_o``
+    (H * v, d)."""
+    d, h = cfg.d_model, cfg.num_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(rng, 6)
+
+    def normal(k, shape, fan_in):
+        return jax.random.normal(k, shape, dtype) * fan_in ** -0.5
+
+    return {"w_qa": normal(ks[0], (d, rq), d),
+            "q_norm": jnp.zeros((rq,), dtype),
+            "w_qb": normal(ks[1], (rq, h, nope + rope), rq),
+            "w_kva": normal(ks[2], (d, rkv + rope), d),
+            "kv_norm": jnp.zeros((rkv,), dtype),
+            "w_uk": normal(ks[3], (h, nope, rkv), rkv),
+            "w_uv": normal(ks[4], (h, dv, rkv), rkv),
+            "w_o": normal(ks[5], (h * dv, d), h * dv)}
+
+
 def decoder_layer_params(rng, cfg: ModelConfig, kind: str, dtype) -> dict:
     if kind == "rwkv":
         return rwkv_mod.rwkv_layer_params(rng, cfg, dtype)
     ks = jax.random.split(rng, 5)
     d = cfg.d_model
     p = {"ln1": jnp.zeros((d,), dtype), "ln2": jnp.zeros((d,), dtype),
-         "attn": _attn_params(ks[0], cfg, dtype)}
+         "attn": (_mla_params(ks[0], cfg, dtype) if cfg.mla
+                  else _attn_params(ks[0], cfg, dtype))}
     if kind == "moe":
         p["moe"] = moe_mod.moe_params(ks[1], cfg, dtype)
         if cfg.moe_shared_d_ff:
@@ -218,7 +252,17 @@ def init_cache(cfg: ModelConfig, spec: CacheSpec) -> dict:
         raise ValueError("per-slot caches do not support enc-dec models")
     cache: dict = {"pos": (jnp.zeros((B,), jnp.int32) if spec.per_slot
                            else jnp.zeros((), jnp.int32))}
-    if not cfg.rwkv:
+    if cfg.mla:
+        # the latent ring: one normed latent and one rope key per token,
+        # shared by every head
+        if jnp.dtype(spec.kv_dtype) == jnp.int8:
+            raise ValueError("latent attention has no int8 ring")
+        cache["ckv"] = jnp.zeros((L, B, C, cfg.kv_lora_rank), spec.kv_dtype)
+        cache["kpe"] = jnp.zeros((L, B, C, cfg.qk_rope_head_dim),
+                                 spec.kv_dtype)
+        cache["slot_pos"] = jnp.full((B, C) if spec.per_slot else (C,),
+                                     _POS_SENTINEL, jnp.int32)
+    elif not cfg.rwkv:
         kv_shape = (L, B, nkv, C, hd)
         cache["k"] = jnp.zeros(kv_shape, spec.kv_dtype)
         cache["v"] = jnp.zeros(kv_shape, spec.kv_dtype)
@@ -339,19 +383,21 @@ def _put_slot(buf: jax.Array, val: jax.Array, li, slot) -> jax.Array:
 def _put_rows(buf: jax.Array, val: jax.Array, li,
               positions: jax.Array) -> jax.Array:
     """Write new rows val (B,H,T,*) into layer ``li`` of a stacked ring
-    (L,B,H,C,*): row r's T tokens sit at the consecutive positions
-    ``positions[r]`` (T <= C), each at its position modulo C.  In-place
-    updates only, one run per row (B is static): a scatter here makes the
-    compiler copy the whole stacked ring around the layer loop.  A run
-    that wraps past slot C-1 is written as two windows, [C-T, C) and
-    [0, T), each keeping the old rows it does not own."""
-    b, h, t, e = val.shape
-    c = buf.shape[3]
+    (L,B,H,C,*) (or val (B,T,*) into a latent ring (L,B,C,*)): row r's T
+    tokens sit at the consecutive positions ``positions[r]`` (T <= C), each
+    at its position modulo C.  In-place updates only, one run per row (B
+    is static): a scatter here makes the compiler copy the whole stacked
+    ring around the layer loop.  A run that wraps past slot C-1 is
+    written as two windows, [C-T, C) and [0, T), each keeping the old rows
+    it does not own."""
+    b, *mid, t, e = val.shape
+    c = buf.shape[-2]
     val = val.astype(buf.dtype)
+    zeros = (0,) * len(mid)
 
     def write(buf, run, r, start):
         return lax.dynamic_update_slice(buf, run[None, None],
-                                        (li, r, 0, start, 0))
+                                        (li, r, *zeros, start, 0))
 
     for r in range(b):
         first = positions[r, 0] % c
@@ -360,11 +406,11 @@ def _put_rows(buf: jax.Array, val: jax.Array, li,
             continue
         start = jnp.minimum(first, c - t)   # the window stays inside C
         wrap = first - start                # the run's last `wrap` tokens
-        run = jnp.roll(val[r], wrap, axis=1)    # land in slots [0, wrap)
-        idx = jnp.arange(t)[None, :, None]
+        run = jnp.roll(val[r], wrap, axis=-2)   # land in slots [0, wrap)
+        idx = jnp.arange(t)[(None,) * len(mid) + (slice(None), None)]
         for lo, mine in ((start, idx >= wrap), (0, idx < wrap)):
-            old = lax.dynamic_slice(buf, (li, r, 0, lo, 0),
-                                    (1, 1, h, t, e))[0, 0]
+            old = lax.dynamic_slice(buf, (li, r, *zeros, lo, 0),
+                                    (1, 1, *mid, t, e))[0, 0]
             buf = write(buf, jnp.where(mine, run, old), r, lo)
     return buf
 
@@ -442,6 +488,108 @@ def _self_attention_slots(x, p, cfg: ModelConfig, spec: AttnSpec,
     return linear(out, p["w_o"]), state
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-0.5, times yarn's mscale squared where the config
+    scales the attention (``mscale_all_dim``)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    yarn = cfg.rope_yarn
+    if yarn is not None and yarn.mscale_all_dim:
+        m = yarn_get_mscale(yarn.factor, yarn.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def _rope_pe(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
+    """Rotate the rope dims as deepseek-v3 does: the interleaved pairs
+    (x[2i], x[2i+1]) are first laid out as two halves (``rotate_half``'s
+    layout), which the result keeps.  sin/cos broadcast against each
+    half."""
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(x.dtype)
+
+
+def _mla_project(x, p, cfg: ModelConfig, positions):
+    """x (B,T,d) at ``positions`` (B,T) or (T,) -> q_nope (B,T,H,nope),
+    the roped q_pe (B,T,H,rope), the normed latent (B,T,latent) and the
+    roped shared key (B,T,rope)."""
+    b, t, _ = x.shape
+    h, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    qa = rms_norm(linear(x, p["w_qa"]), p["q_norm"], cfg.norm_eps)
+    q = jnp.einsum("btr,rhe->bthe", qa, p["w_qb"])
+    q_nope, q_pe = q[..., :nope], q[..., nope:]
+    kv = linear(x, p["w_kva"])
+    latent = rms_norm(kv[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    k_pe = kv[..., cfg.kv_lora_rank:]
+    sin, cos = rope_tables(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                           cfg.rope_yarn)
+    if positions.ndim == 1:
+        sin, cos = sin[None], cos[None]                 # (1,T,rope/2)
+    q_pe = _rope_pe(q_pe, sin[:, :, None], cos[:, :, None])
+    return q_nope, q_pe, latent, _rope_pe(k_pe, sin, cos)
+
+
+def mla_attend(q_nope, q_pe, ckv, kpe, bias, p, cfg: ModelConfig):
+    """Absorbed latent attention: q_nope (B,T,H,nope) through each head's
+    key up-projection gives a latent query, scored against the latent ring
+    ckv (B,S,latent) plus q_pe (B,T,H,rope) against the shared rope keys
+    kpe (B,S,rope); the weighted latents go through each head's value
+    up-projection.  bias (B or 1,1,T,S) masks.  Returns (B,T,H,v)."""
+    q_lat = jnp.einsum("bthn,hnc->bthc", q_nope, p["w_uk"])
+    scores = (jnp.einsum("bthc,bsc->bhts", q_lat, ckv,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bthr,bsr->bhts", q_pe, kpe,
+                           preferred_element_type=jnp.float32))
+    scores = scores * mla_softmax_scale(cfg) + bias
+    probs = jax.nn.softmax(scores, axis=-1)
+    probs = jnp.where(jnp.isnan(probs), 0.0, probs)  # rows with no key
+    o_lat = jnp.einsum("bhts,bsc->bthc", probs.astype(ckv.dtype), ckv)
+    return jnp.einsum("bthc,hvc->bthv", o_lat, p["w_uv"])
+
+
+@jax.named_scope("attn")
+def _mla_attention(x, p, cfg: ModelConfig, ctx: dict, state: Optional[dict],
+                   li):
+    """Latent attention in every mode that has one.  ``slots`` (the decode
+    step and the prefill chunk) and lock-step ``decode`` write each row's
+    latent and rope key into layer ``li`` of the stacked latent ring and
+    attend over the ring's tagged slots; ``train``/``prefill`` attend
+    causally over the sequence (``prefill`` also lays it out as the
+    ring).  Returns (out (B,T,d), state)."""
+    b, t, _ = x.shape
+    mode = ctx["mode"]
+    if mode in ("train", "prefill"):
+        positions = ctx["positions"]
+        q_nope, q_pe, latent, k_pe = _mla_project(x, p, cfg, positions)
+        causal = positions[None, :] <= positions[:, None]
+        bias = jnp.where(causal, 0.0, -jnp.inf).astype(jnp.float32)
+        out = mla_attend(q_nope, q_pe, latent, k_pe, bias[None, None], p, cfg)
+        if state is not None:          # prefill: the layer's whole ring
+            c = state["ckv"].shape[-2]
+            state = {**state, **{
+                key: _put(state[key], _fill_ring(val[:, -c:], c, t), li)
+                for key, val in (("ckv", latent), ("kpe", k_pe))}}
+    elif mode in ("slots", "decode"):
+        slot_pos = ctx["slot_pos"]
+        if mode == "decode":           # every row at the one position
+            positions = jnp.full((b, 1), ctx["pos"], jnp.int32)
+            slot_pos = jnp.broadcast_to(slot_pos, (b, slot_pos.shape[-1]))
+        else:
+            positions = ctx["positions"]
+        q_nope, q_pe, latent, k_pe = _mla_project(x, p, cfg, positions)
+        state = {**state,
+                 "ckv": _put_rows(state["ckv"], latent, li, positions),
+                 "kpe": _put_rows(state["kpe"], k_pe, li, positions)}
+        ok = slot_pos[:, None, :] <= positions[:, :, None]     # (B,T,C)
+        bias = jnp.where(ok, 0.0, -jnp.inf).astype(jnp.float32)[:, None]
+        out = mla_attend(q_nope, q_pe, _at(state["ckv"], li),
+                         _at(state["kpe"], li), bias, p, cfg)
+    else:
+        raise ValueError(f"latent attention has no {mode!r} mode")
+    return linear(out.reshape(b, t, -1), p["w_o"]), state
+
+
 @jax.named_scope("attn")
 def _cross_attention(x, p, cfg: ModelConfig, ck, cv, mesh=None):
     """Cross-attention, q-chunked like self-attention: the unchunked
@@ -476,12 +624,25 @@ def _ffn(x, p, cfg: ModelConfig, kind: str, mode: str = "train",
     return mlp(x, p["mlp"], cfg, mesh)
 
 
+@jax.named_scope("mlp")
+def _held_moe(x, p, cfg: ModelConfig, mesh=None):
+    """Sigmoid-routed experts, of which this chip computes those it holds,
+    plus the shared expert.  Returns (out, rows per held expert)."""
+    b, s, d = x.shape
+    out, rows = moe_mod.held_moe_ffn(x.reshape(b * s, d), p["moe"], cfg)
+    with jax.named_scope("shared_expert"):
+        out = out.reshape(b, s, d) + mlp(x, p["shared_mlp"], cfg, mesh)
+    return out, rows
+
+
 @jax.named_scope("layer")
 def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,
-                   ctx: dict, state: Optional[dict], li):
+                   ctx: dict, state: Optional[dict], li, rows=None):
     """Apply decoder layer ``li``.  ``state`` holds the cache leaves stacked
     over layers (None without a cache); the layer writes what it changed
-    into them in place.  Returns (x, state)."""
+    into them in place.  A held share of sigmoid-routed experts appends
+    the rows it routed to each held expert (E_held,) int32 to ``rows``,
+    where given.  Returns (x, state)."""
     if kind == "rwkv":
         prev = None if state is None else _at(state["rwkv_state"], li)
         x, new = rwkv_mod.rwkv_block(x, p, cfg, prev)
@@ -490,7 +651,9 @@ def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,
         return x, state
 
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if ctx["mode"] == "decode":
+    if cfg.mla:
+        attn_out, state = _mla_attention(h, p["attn"], cfg, ctx, state, li)
+    elif ctx["mode"] == "decode":
         attn_out, state = _self_attention_decode(
             h, p["attn"], cfg, spec, state, li, ctx["slot_pos"], ctx["pos"])
     elif ctx["mode"] == "slots":
@@ -522,21 +685,29 @@ def _decoder_layer(x, p, cfg: ModelConfig, kind: str, spec: AttnSpec,
                                  ctx.get("mesh"))
 
     h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+    if kind == "moe" and cfg.moe_router == "sigmoid":
+        out, routed = _held_moe(h2, p, cfg, ctx.get("mesh"))
+        if rows is not None:
+            rows.append(routed)
+        return x + out, state
     x = x + _ffn(h2, p, cfg, kind, ctx["mode"], ctx.get("mesh"))
     return x, state
 
 
 @jax.named_scope("kv_ring")
 def _fill_ring(val: jax.Array, c: int, total_seq: int) -> jax.Array:
-    """Lay a prefill's last-C tokens val (B,H,min(S,C),*) out as a ring of
-    capacity ``c``: position ``p`` lands at slot ``p % C`` (so later decode
-    steps evict exactly the token leaving the window)."""
-    s = val.shape[2]           # = min(total_seq, c)
+    """Lay a prefill's last-C tokens val (B,H,min(S,C),*) (or a latent's
+    (B,min(S,C),*)) out as a ring of capacity ``c``: position ``p`` lands
+    at slot ``p % C`` (so later decode steps evict exactly the token
+    leaving the window)."""
+    s = val.shape[-2]          # = min(total_seq, c)
     if s < c:
-        val = jnp.pad(val, ((0, 0), (0, 0), (0, c - s), (0, 0)))
+        pad = [(0, 0)] * val.ndim
+        pad[-2] = (0, c - s)
+        val = jnp.pad(val, pad)
     shift = (total_seq - s) % c
     if shift:
-        val = jnp.roll(val, shift, axis=2)
+        val = jnp.roll(val, shift, axis=-2)
     return val
 
 
@@ -609,7 +780,8 @@ def _seq_shard_hidden(x, mesh):
         x, NamedSharding(mesh, P(b_ax, "model", None)))
 
 
-_LAYER_KEYS = ("k", "v", "k_scale", "v_scale", "rwkv_state", "ssm_state")
+_LAYER_KEYS = ("k", "v", "k_scale", "v_scale", "ckv", "kpe", "rwkv_state",
+               "ssm_state")
 
 
 def _layer_state(cache: Optional[dict]) -> Optional[dict]:
@@ -619,13 +791,16 @@ def _layer_state(cache: Optional[dict]) -> Optional[dict]:
     return {key: cache[key] for key in _LAYER_KEYS if key in cache}
 
 
-def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
+def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False,
+                 rows=None):
     """Apply prefix layers then the scanned blocks.  The cache's stacked
     leaves ride in the loop's carry: layer ``prefix + block * period +
     slot`` reads its slice at that index and writes its new K/V rows (or
     its whole state) there in place, so the ring is neither sliced into
-    the scan's xs nor restacked from its ys.  Returns (x, the updated
-    stacked leaves, or None without a cache)."""
+    the scan's xs nor restacked from its ys.  Where ``rows`` is a list,
+    each held-expert layer's routed rows are appended to it in layer
+    order (the scanned blocks' as the scan's output).  Returns (x, the
+    updated stacked leaves, or None without a cache)."""
     prefix, period, n_blocks = block_structure(cfg)
     kinds = layer_kinds(cfg)
     spec = attn_spec(cfg)
@@ -633,19 +808,20 @@ def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
 
     for li in range(prefix):
         x, state = _decoder_layer(x, params["prefix"][li], cfg, kinds[li],
-                                  spec, ctx, state, li)
+                                  spec, ctx, state, li, rows)
     if not n_blocks:
         return x, state
 
     def block_body(carry, xs):
         x, state = carry
         bparams, bi = xs
+        mine = None if rows is None else []
         for slot in range(period):
             x, state = _decoder_layer(x, bparams[slot], cfg,
                                       kinds[prefix + slot], spec, ctx, state,
-                                      prefix + bi * period + slot)
+                                      prefix + bi * period + slot, mine)
         x = _seq_shard_hidden(x, ctx.get("mesh"))
-        return (x, state), None
+        return (x, state), (jnp.stack(mine) if mine else None)
 
     if remat:
         block_body = jax.checkpoint(block_body)
@@ -654,12 +830,16 @@ def _run_decoder(params, cfg: ModelConfig, x, ctx, cache, remat=False):
         # Python loop: accounting variants (cost_analysis counts every
         # unrolled body; a while/scan body is counted once)
         for bi in range(n_blocks):
-            (x, state), _ = block_body(
+            (x, state), ys = block_body(
                 (x, state), (jax.tree.map(lambda a: a[bi], bparams), bi))
+            if ys is not None:
+                rows.extend(ys)
     else:
-        (x, state), _ = lax.scan(
+        (x, state), ys = lax.scan(
             block_body, (x, state),
             (bparams, jnp.arange(n_blocks, dtype=jnp.int32)))
+        if ys is not None:
+            rows.extend(ys.reshape(-1, ys.shape[-1]))
     return x, state
 
 
@@ -832,6 +1012,22 @@ def decode_slots(params, cfg: ModelConfig, token: jax.Array, cache: dict,
     (``CacheSpec(per_slot=True)``: pos (B,), slot_pos (B,C)).  Every slot
     advances by one token at its *own* position -> (logits (B,1,V), cache).
     """
+    return _decode_slots(params, cfg, token, cache, mesh)
+
+
+def decode_slots_counted(params, cfg: ModelConfig, token: jax.Array,
+                         cache: dict, mesh=None):
+    """``decode_slots`` of a held share of sigmoid-routed experts, which
+    also returns the rows the step routed to each held expert of every
+    expert layer -> (logits, cache, rows (expert layers, E_held) int32)."""
+    rows: list = []
+    logits, cache = _decode_slots(params, cfg, token, cache, mesh, rows)
+    if not rows:
+        raise ValueError("the config holds no sigmoid-routed experts")
+    return logits, cache, jnp.stack(rows)
+
+
+def _decode_slots(params, cfg: ModelConfig, token, cache, mesh, rows=None):
     if cfg.encoder_layers:
         raise ValueError("decode_slots does not support enc-dec models")
     x = jnp.take(params["embed"], token[:, None], axis=0)
@@ -840,7 +1036,7 @@ def decode_slots(params, cfg: ModelConfig, token: jax.Array, cache: dict,
     pos = cache["pos"]                                  # (B,)
     positions = pos[:, None]                            # (B,1)
     ctx, slot_pos = _slots_ctx(cache, positions, mesh)
-    x, state = _run_decoder(params, cfg, x, ctx, cache)
+    x, state = _run_decoder(params, cfg, x, ctx, cache, rows=rows)
     new_cache = _merge_cache(cache, state, pos + 1, slot_pos)
     return _logits(params, cfg, x), new_cache
 

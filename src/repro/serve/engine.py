@@ -89,8 +89,9 @@ def tier_kv_capacity(cfg: ModelConfig, chip, *, batch: int,
     finite staging tiers (e.g. ``ipu_pod4().with_stacked_dram()``), the
     stacked bytes left after the weights that spill out of SRAM bound the
     cache:  ``tokens = (tier_bytes - weight_spill) // (batch * per_token)``
-    with ``per_token = num_layers * 2 * num_kv_heads * head_dim *
-    itemsize(kv_dtype)``.
+    with ``per_token`` the bytes of one token in the rings ``init_cache``
+    builds (``num_layers * 2 * num_kv_heads * head_dim * itemsize`` for
+    K/V, ``num_layers * (latent + rope) * itemsize`` for a latent ring).
     """
     if chip is None:
         return 0
@@ -98,10 +99,18 @@ def tier_kv_capacity(cfg: ModelConfig, chip, *, batch: int,
     if not tiers or any(t.unbounded for t in tiers):
         return 0
     left = _tier_bytes_left(cfg, chip)
-    hd = cfg.resolved_head_dim
-    per_token = (cfg.num_layers * 2 * cfg.num_kv_heads * hd
-                 * jnp.dtype(kv_dtype).itemsize)
+    per_token = _ring_bytes_per_token(cfg, kv_dtype)
     return int(left // max(batch * per_token, 1))
+
+
+def _ring_bytes_per_token(cfg: ModelConfig, kv_dtype: str) -> int:
+    """Bytes one cached token takes over every layer: the ring leaves that
+    ``init_cache`` builds (K/V, or the latent and its rope key), per slot
+    of capacity."""
+    spec = tfm.CacheSpec(capacity=1, batch=1, kv_dtype=jnp.dtype(kv_dtype))
+    shape = jax.eval_shape(lambda: tfm.init_cache(cfg, spec))
+    return int(sum(int(np.prod(shape[key].shape)) * shape[key].dtype.itemsize
+                   for key in ("k", "v", "ckv", "kpe") if key in shape))
 
 
 def _tier_bytes_left(cfg: ModelConfig, chip) -> int:
@@ -332,6 +341,9 @@ class ServeEngine:
         # last-position logits (rows, V) of the latest ``step`` or
         # ``prefill_chunk``: what the slot path's greedy token came from
         self.last_logits: Optional[jax.Array] = None
+        # routed rows per held expert (expert layers, E_held) of the
+        # latest ``step(count_experts=True)``
+        self.last_expert_rows: Optional[jax.Array] = None
 
     # -- public API --------------------------------------------------------
     def prefill(self, tokens: jax.Array, cache: Optional[dict] = None,
@@ -399,12 +411,25 @@ class ServeEngine:
             last = logits[:, -1, :]
             return jnp.argmax(last, axis=-1).astype(jnp.int32), last, cache
 
+        def counted_step(params, token, cache):
+            logits, cache, rows = tfm.decode_slots_counted(
+                params, cfg, token, cache, mesh=mesh)
+            last = logits[:, -1, :]
+            return (jnp.argmax(last, axis=-1).astype(jnp.int32), last, cache,
+                    rows)
+
+        outs = (self._tok_sh,
+                NamedSharding(mesh, P(batch_axes(mesh), "model")), slot_sh)
         self._step_slots = jax.jit(
             step,
             in_shardings=(self.p_sh, self._tok_sh, slot_sh),
-            out_shardings=(self._tok_sh,
-                           NamedSharding(mesh, P(batch_axes(mesh), "model")),
-                           slot_sh),
+            out_shardings=outs,
+            donate_argnums=(2,),
+        )
+        self._counted_step = jax.jit(
+            counted_step,
+            in_shardings=(self.p_sh, self._tok_sh, slot_sh),
+            out_shardings=outs + (rep,),
             donate_argnums=(2,),
         )
         self._insert = jax.jit(tfm.cache_insert_slot,
@@ -499,11 +524,21 @@ class ServeEngine:
         return int(sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
                        for leaf in jax.tree.leaves(shape)))
 
-    def step(self, tokens: jax.Array) -> jax.Array:
+    def step(self, tokens: jax.Array, count_experts: bool = False
+             ) -> jax.Array:
         """One continuous-batching decode step over the mutable slot batch:
         tokens (slots,) int32 -> greedy next token per slot (slots,).  The
-        slot cache advances in place (donated buffers, no copy)."""
+        slot cache advances in place (donated buffers, no copy).  With
+        ``count_experts`` (a held share of sigmoid-routed experts, one
+        device's mesh) the step is a program of its own that also keeps
+        the rows it routed to each held expert of every expert layer in
+        ``last_expert_rows``; the plain step counts nothing."""
         self._ensure_slots()
+        if count_experts:
+            (tok, self.last_logits, self.slot_cache,
+             self.last_expert_rows) = self._counted_step(
+                self.params, tokens, self.slot_cache)
+            return tok
         tok, self.last_logits, self.slot_cache = self._step_slots(
             self.params, tokens, self.slot_cache)
         return tok

@@ -149,10 +149,14 @@ def test_chunk_prefill_step_calls_kernel(topo, on_tpu):
     _assert_scoped(compiled.as_text())
 
 
-# the benchmark's two configurations: (config, layers, slots, ring tokens)
+# the benchmark's configurations: (config, layers, slots, ring tokens)
 BENCH_STEPS = {
     "h2o_danube_1_8b": (get_config("h2o_danube_1_8b"), 24, 8, 4224),
     "qwen3_14b": (QWEN3, 12, 12, 4096),
+    # one chip's share: 12 of each layer's 384 experts, a latent ring
+    "kimi_k2_1t_a32b": (dataclasses.replace(get_config("kimi_k2_1t_a32b"),
+                                            moe_experts_held=12),
+                        6, 16, 12288),
 }
 
 
@@ -191,11 +195,13 @@ def test_slot_decode_step_keeps_ring_in_place(topo, on_tpu, name):
                    donate_argnums=(2,))
     compiled = step.lower(params, tok, cache).compile()
 
-    ring = cache_shapes["k"]
-    layer_ring = 2 * ring.size // layers * ring.dtype.itemsize     # k + v
+    rings = [cache_shapes[k] for k in ("k", "v", "ckv", "kpe")
+             if k in cache_shapes]
+    layer_ring = sum(r.size * r.dtype.itemsize for r in rings) // layers
     layer_weights = sum(a.size * a.dtype.itemsize
                         for a in jax.tree.leaves(shapes["blocks"])) // layers
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < layer_ring + layer_weights, (temp, layer_ring,
                                                layer_weights)
-    assert not _ring_moves(compiled.as_text(), ring.shape)
+    for ring in rings:
+        assert not _ring_moves(compiled.as_text(), ring.shape)

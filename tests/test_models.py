@@ -109,7 +109,11 @@ def _slots_against_forward(cfg, rng, kv_dtype, tol):
     lens, new = (5, 9, 12), 4
     tokens = jax.random.randint(rng, (len(lens), max(lens) + new), 0,
                                 cfg.vocab_size)
-    full = T.forward_train(params, cfg, tokens, remat=False)
+    # compiled, as the slot path is: XLA keeps a fused chain's bfloat16
+    # intermediates in float32, so an op-by-op forward of unrolled layers
+    # (kimi's dense first layer) rounds differently
+    full = jax.jit(lambda p, t: T.forward_train(p, cfg, t, remat=False))(
+        params, tokens)
 
     def check(r, p, got):
         ref = full[r, p].astype(jnp.float32)
